@@ -1,17 +1,9 @@
 #!/usr/bin/env python3
-"""Fail fast when the installed JAX is outside the range supported by
-``repro.kernels.common.tpu_compiler_params``.
+"""Fail fast when the entry points the tier-1 gate relies on stop resolving
+on the installed JAX.  Run from ``scripts/tier1.sh``:
 
-The Pallas TPU compiler-params class has been renamed across JAX releases
-(``TPUCompilerParams`` -> ``CompilerParams``); ``tpu_compiler_params``
-resolves whichever exists at call time and silently returns ``None`` when it
-can't.  That silence is fine inside a kernel call (defaults apply) but means
-the *next* rename only surfaces as a slow drift in kernel behaviour.  This
-check — run from ``scripts/tier1.sh`` — turns it into a loud, actionable
-failure:
-
-  * JAX older/newer than the explicitly supported range  -> exit 1
-  * pltpu importable but neither params class resolvable -> exit 1
+  * ``repro.kernels.common.tpu_compiler_params`` cannot build
+    ``pltpu.CompilerParams`` with the kernels' dimension semantics -> exit 1
   * the ``kernels/state_push`` entry points (the wire codec dispatched from
     ``LocalTier.push_delta(wire="int8")``) fail to import or to quantise a
     trivial delta                                        -> exit 1
@@ -33,26 +25,12 @@ Invoked standalone:  python scripts/check_jax_pin.py
 from __future__ import annotations
 
 import os
-import re
 import sys
-
-# The range tpu_compiler_params is known to resolve against (ROADMAP
-# "Kernel API pinning").  Bump MAX when a new JAX release is verified.
-SUPPORTED_MIN = (0, 4, 26)
-SUPPORTED_MAX_EXCLUSIVE = (0, 8, 0)
-
-
-def _parse(version: str):
-    nums = re.findall(r"\d+", version)[:3]
-    if not nums:
-        return None
-    return tuple(int(n) for n in (nums + ["0", "0"])[:3])
 
 
 def check_analysis_entry_points() -> int:
     """The isolation checker's entry points must resolve and its hooks must
-    install/uninstall — run before the jax probes so a jax-less container
-    still verifies the instrumentation isn't orphaned."""
+    install/uninstall, so a refactor cannot orphan the instrumentation."""
     try:
         from repro.analysis import holds_stripe              # noqa: F401
         from repro.analysis.lint import RULES, lint_source
@@ -288,49 +266,24 @@ def main() -> int:
     if rc:
         return rc
 
-    try:
-        import jax
-    except ImportError as e:
-        print(f"check_jax_pin: jax not importable ({e}); kernels will fall "
-              "back to XLA — skipping pin check")
-        return 0
-
-    ver = _parse(jax.__version__)
-    if ver is None:
-        print(f"check_jax_pin: FAIL — cannot parse jax version "
-              f"{jax.__version__!r}")
-        return 1
-    if not (SUPPORTED_MIN <= ver < SUPPORTED_MAX_EXCLUSIVE):
-        lo = ".".join(map(str, SUPPORTED_MIN))
-        hi = ".".join(map(str, SUPPORTED_MAX_EXCLUSIVE))
-        print(f"check_jax_pin: FAIL — jax {jax.__version__} outside the "
-              f"supported range [{lo}, {hi}) for tpu_compiler_params.\n"
-              f"  Verify pltpu.CompilerParams/TPUCompilerParams still "
-              f"resolve in src/repro/kernels/common.py, run the slow kernel "
-              f"matrix (pytest -m slow), then bump the pin here.")
-        return 1
+    import jax
 
     try:
-        from jax.experimental.pallas import tpu as pltpu
-    except ImportError as e:
-        print(f"check_jax_pin: pallas TPU backend not importable ({e}); "
-              "interpret-mode tests cover the kernels — OK")
-        return 0
-
-    cls = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams", None)
-    if cls is None:
-        print("check_jax_pin: FAIL — jax.experimental.pallas.tpu exposes "
-              "neither CompilerParams nor TPUCompilerParams (another "
-              "rename?).  Update tpu_compiler_params() in "
-              "src/repro/kernels/common.py and this pin.")
+        from repro.kernels.common import tpu_compiler_params
+        params = tpu_compiler_params(("parallel", "arbitrary"))
+        assert params.dimension_semantics == ("parallel", "arbitrary"), params
+    except Exception as e:
+        print(f"check_jax_pin: FAIL — tpu_compiler_params cannot build "
+              f"pltpu.CompilerParams under jax {jax.__version__}: {e!r}\n"
+              f"  Every Pallas kernel passes its grid semantics through it; "
+              f"fix src/repro/kernels/common.py.")
         return 1
 
     # the quantised wire codec is dispatched from the state tier on every
     # int8 push_delta, delta pull and peer broadcast: make a JAX drift there
-    # loud, not a slow failure at transfer time.  Runs after the pltpu
-    # probes above so a pallas rename hits its targeted diagnostic first,
-    # not this generic one.
+    # loud, not a slow failure at transfer time.  Runs after the
+    # compiler-params probe above so a pallas API change hits its targeted
+    # diagnostic first, not this generic one.
     try:
         from repro.kernels.state_push import (apply_pull, dequantize,
                                               encode_pull, quantize_delta)
@@ -356,8 +309,7 @@ def main() -> int:
               f"src/repro/kernels/state_push/ before trusting the tier.")
         return 1
 
-    print(f"check_jax_pin: OK — jax {jax.__version__}, params class "
-          f"pltpu.{cls.__name__}")
+    print(f"check_jax_pin: OK — jax {jax.__version__}")
     return 0
 
 

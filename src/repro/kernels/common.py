@@ -11,11 +11,9 @@ interpreter.  ``default_backend()`` picks the dispatch used by the model code:
 """
 from __future__ import annotations
 
-import functools
-import os
-
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 
 from repro import cancellation
 
@@ -25,11 +23,6 @@ NEG_INF = float(-1e30)   # large-negative instead of -inf: keeps bf16 softmax Na
 
 
 def default_backend() -> str:
-    forced = os.environ.get("REPRO_KERNEL_BACKEND")
-    if forced:
-        if forced not in BACKENDS:
-            raise ValueError(f"REPRO_KERNEL_BACKEND={forced!r} not in {BACKENDS}")
-        return forced
     return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
@@ -50,27 +43,9 @@ def interpret_mode(backend: str) -> bool:
     return backend == "pallas_interpret"
 
 
-def tpu_compiler_params(dimension_semantics=None, **kwargs):
-    """Build Pallas TPU compiler params across JAX versions.
-
-    Newer JAX exposes ``pltpu.CompilerParams``; older releases call it
-    ``TPUCompilerParams``.  Returns ``None`` when neither is constructible,
-    which ``pl.pallas_call`` accepts (defaults apply).
-    """
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-    except ImportError:
-        return None
-    cls = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams", None)
-    if cls is None:
-        return None
-    if dimension_semantics is not None:
-        kwargs["dimension_semantics"] = tuple(dimension_semantics)
-    try:
-        return cls(**kwargs)
-    except TypeError:
-        return None
+def tpu_compiler_params(dimension_semantics):
+    """Pallas TPU compiler params carrying the grid's dimension semantics."""
+    return pltpu.CompilerParams(dimension_semantics=tuple(dimension_semantics))
 
 
 def cdiv(a: int, b: int) -> int:

@@ -10,6 +10,9 @@ the kernel is organised around streaming the KV cache through VMEM exactly once:
     arithmetic-intensity win: bytes/token divided by G).
   * per-sequence cache lengths arrive via scalar prefetch (SMEM) and mask the
     tail tile; whole splits past the length are elided with ``pl.when``.
+  * k/v are heads-major, (B, K, S, D), so each streamed tile's last two dims
+    are (block_k, head_dim) as Mosaic requires; ``ops.py`` transposes the
+    model's (B, S, K, D) cache.
 """
 from __future__ import annotations
 
@@ -39,9 +42,9 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *
 
     @pl.when(ik * block_k < length)
     def _compute():
-        q = q_ref[0, 0, :, :].astype(jnp.float32) * scale            # (Gp, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)                     # (bk, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32) * scale            # (Gp, D)
+        k = k_ref[...].astype(jnp.float32)                     # (bk, D)
+        v = v_ref[...].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)   # (Gp, bk)
         k_pos = ik * block_k + jax.lax.broadcasted_iota(
@@ -63,14 +66,14 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *
     def _finalize():
         l = l_ref[:, 0]
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0, :, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
 
 
 def decode_attention_pallas(q, k, v, lengths, *, scale: float | None = None,
                             block_k: int = 512, interpret: bool = False):
-    """q: (B, K, Gp, D) grouped+padded queries; k/v: (B, S, K, D); lengths: (B,)."""
+    """q: (B, K, Gp, D) grouped+padded queries; k/v: (B, K, S, D); lengths: (B,)."""
     B, K, Gp, D = q.shape
-    _, S, _, _ = k.shape
+    S = k.shape[2]
     if scale is None:
         scale = D ** -0.5
     block_k = min(block_k, S)
@@ -79,15 +82,14 @@ def decode_attention_pallas(q, k, v, lengths, *, scale: float | None = None,
 
     kernel = functools.partial(_decode_kernel, scale=scale, block_k=block_k,
                                n_splits=n_splits, g_pad=Gp)
+    q_spec = pl.BlockSpec((None, None, Gp, D), lambda b, h, ik, lens: (b, h, 0, 0))
+    kv_spec = pl.BlockSpec((None, None, block_k, D),
+                           lambda b, h, ik, lens: (b, h, ik, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, K, n_splits),
-        in_specs=[
-            pl.BlockSpec((1, 1, Gp, D), lambda b, h, ik, lens: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, ik, lens: (b, ik, h, 0)),
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, ik, lens: (b, ik, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, Gp, D), lambda b, h, ik, lens: (b, h, 0, 0)),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((Gp, D), jnp.float32),
             pltpu.VMEM((Gp, _MIN_LANES), jnp.float32),

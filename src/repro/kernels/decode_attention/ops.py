@@ -52,8 +52,9 @@ def _decode_pallas(q, k, v, lengths, *, scale, block_k, interpret):
     S_p = round_up(S, min(block_k, round_up(S, 8)))
     block_k = min(block_k, S_p)
     S_p = round_up(S_p, block_k)
-    kp = pad_axis_to(k, 1, S_p)
-    vp = pad_axis_to(v, 1, S_p)
+    # the kernel streams a heads-major cache: (B, S, K, D) -> (B, K, S, D)
+    kp = pad_axis_to(k, 1, S_p).swapaxes(1, 2)
+    vp = pad_axis_to(v, 1, S_p).swapaxes(1, 2)
     out = decode_attention_pallas(qg, kp, vp, lengths.astype(jnp.int32),
                                   scale=scale, block_k=block_k,
                                   interpret=interpret)

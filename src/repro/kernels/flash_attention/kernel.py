@@ -4,6 +4,9 @@ Design (TPU-native, not a CUDA port):
   * grid = (batch, q_heads, q_blocks, kv_blocks); the kv dimension is
     ``arbitrary`` (sequential) so the online-softmax accumulators live in VMEM
     scratch across kv steps — HBM sees each q/k/v tile exactly once.
+  * operands are heads-major, (B, H, S, D): each block's last two dims are a
+    (block, head_dim) tile, which Mosaic needs as (8k or full, 128k or full).
+    ``ops.py`` transposes to and from the model's (B, S, H, D).
   * q tile (block_q, head_dim) stays resident; k/v tiles stream through VMEM.
     block sizes default to 128 to align with the 128×128 MXU and 8×128 VREG lanes.
   * causal blocks strictly above the diagonal are skipped via ``pl.when``
@@ -44,9 +47,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
     @pl.when(needed)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale          # (bq, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)                  # (bk, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)                  # (bk, D)
+        q = q_ref[...].astype(jnp.float32) * scale          # (bq, D)
+        k = k_ref[...].astype(jnp.float32)                  # (bk, D)
+        v = v_ref[...].astype(jnp.float32)                  # (bk, D)
 
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
@@ -78,7 +81,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     def _finalize():
         l = l_ref[:, 0]
         l = jnp.where(l == 0.0, 1.0, l)                             # fully-masked rows
-        o_ref[0, :, 0, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
 
 
 def flash_attention_pallas(q, k, v, *, causal: bool = True,
@@ -86,9 +89,9 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
                            kv_len: int | None = None,
                            block_q: int = 128, block_k: int = 128,
                            interpret: bool = False):
-    """(B, Sq, H, D) x (B, Sk, K, D)^2 -> (B, Sq, H, D).  Sq/Sk padded by ops.py."""
-    B, Sq, H, D = q.shape
-    _, Sk, K, _ = k.shape
+    """(B, H, Sq, D) x (B, K, Sk, D)^2 -> (B, H, Sq, D).  Sq/Sk padded by ops.py."""
+    B, H, Sq, D = q.shape
+    _, K, Sk, _ = k.shape
     assert H % K == 0
     group = H // K
     if scale is None:
@@ -104,12 +107,10 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
         block_k=block_k, n_kv_blocks=n_k, q_offset=q_offset, kv_len=kv_len)
 
     grid = (B, H, n_q, n_k)
-    in_specs = [
-        pl.BlockSpec((1, block_q, 1, D), lambda b, h, iq, ik: (b, iq, h, 0)),
-        pl.BlockSpec((1, block_k, 1, D), lambda b, h, iq, ik: (b, ik, h // group, 0)),
-        pl.BlockSpec((1, block_k, 1, D), lambda b, h, iq, ik: (b, ik, h // group, 0)),
-    ]
-    out_specs = pl.BlockSpec((1, block_q, 1, D), lambda b, h, iq, ik: (b, iq, h, 0))
+    q_spec = pl.BlockSpec((None, None, block_q, D),
+                          lambda b, h, iq, ik: (b, h, iq, 0))
+    kv_spec = pl.BlockSpec((None, None, block_k, D),
+                           lambda b, h, iq, ik: (b, h // group, ik, 0))
 
     compiler_params = tpu_compiler_params(
         ("parallel", "parallel", "parallel", "arbitrary"))
@@ -117,8 +118,8 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, D), jnp.float32),          # acc

@@ -47,13 +47,14 @@ def _flash_pallas_padded(q, k, v, *, causal, scale, q_offset, block_q, block_k,
     Sk_p = round_up(Sk, block_k) if Sk >= block_k else round_up(Sk, 8)
     block_k = min(block_k, Sk_p)
     Sk_p = round_up(Sk_p, block_k)
-    qp = pad_axis_to(q, 1, Sq_p)
-    kp = pad_axis_to(k, 1, Sk_p)
-    vp = pad_axis_to(v, 1, Sk_p)
+    # the kernel is heads-major: (B, S, H, D) -> (B, H, S, D) and back
+    qp = pad_axis_to(q, 1, Sq_p).swapaxes(1, 2)
+    kp = pad_axis_to(k, 1, Sk_p).swapaxes(1, 2)
+    vp = pad_axis_to(v, 1, Sk_p).swapaxes(1, 2)
     out = flash_attention_pallas(
         qp, kp, vp, causal=causal, scale=scale, q_offset=q_offset,
         kv_len=Sk, block_q=block_q, block_k=block_k, interpret=interpret)
-    return out[:, :Sq]
+    return out.swapaxes(1, 2)[:, :Sq]
 
 
 # ---------------------------------------------------------------------------

@@ -38,8 +38,12 @@ def ssd(x, dt, A, B, C, D_skip, *, chunk: int = 256, initial_state=None,
     if b == "xla":
         y, final = _ssd_xla(xp, dtp, A, Bp, Cp, D_skip, initial_state, chunk)
     else:
-        y, final = ssd_pallas(xp, dtp, A, Bp, Cp, D_skip, initial_state,
-                              chunk=chunk, interpret=(b == "pallas_interpret"))
+        # the kernel is heads-major: (Bt, S, H, ·) -> (Bt, H, S, ·) and back
+        y, final = ssd_pallas(xp.swapaxes(1, 2), dtp.swapaxes(1, 2), A,
+                              Bp.swapaxes(1, 2), Cp.swapaxes(1, 2), D_skip,
+                              initial_state, chunk=chunk,
+                              interpret=(b == "pallas_interpret"))
+        y = y.swapaxes(1, 2)
     return y[:, :S], final
 
 

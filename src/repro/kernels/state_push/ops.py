@@ -52,11 +52,21 @@ def _to_rows(x):
     return padded.reshape(rows, LANES), n
 
 
-def _block_rows(rows: int) -> int:
-    for b in (256, 64, 8, 1):
-        if rows % b == 0:
-            return b
-    return 1
+def _pallas_rows(kernel, *operands, interpret: bool, **kw):
+    """Run a row-streaming state-push kernel over (R, ·) operands.
+
+    Blocks are (8k, 128) tiles of at most 256 rows, as Mosaic requires, so
+    the rows are zero-padded to a whole number of blocks.  Zero rows
+    quantise to zero codes and apply as a no-op; every output is trimmed
+    back to R rows."""
+    rows = operands[0].shape[0]
+    blk = min(256, round_up(rows, 8))
+    pad = round_up(rows, blk) - rows
+    out = kernel(*(jnp.pad(x, ((0, pad), (0, 0))) for x in operands),
+                 block_rows=blk, interpret=interpret, **kw)
+    if isinstance(out, (list, tuple)):
+        return tuple(o[:rows] for o in out)
+    return out[:rows]
 
 
 @functools.partial(jax.jit, static_argnames=("qmax", "with_residual"))
@@ -97,12 +107,11 @@ def _device_encode(eff, base, *, qmax, fp8, b, with_residual):
         lr, _ = _to_rows(eff)
         br, _ = _to_rows(base)
         interp = b == "pallas_interpret"
-        blk = _block_rows(rows)
         if fp8:
-            q, s = quantize_fp8_pallas(lr, br, block_rows=blk, interpret=interp)
+            q, s = _pallas_rows(quantize_fp8_pallas, lr, br, interpret=interp)
         else:
-            q, s = quantize_delta_pallas(lr, br, block_rows=blk,
-                                         interpret=interp, qmax=float(qmax))
+            q, s = _pallas_rows(quantize_delta_pallas, lr, br,
+                                interpret=interp, qmax=float(qmax))
         qn, sn = np.asarray(q), np.asarray(s)
         if not with_residual:
             return qn, sn, n, None
@@ -191,9 +200,9 @@ def quantize_delta(local, base, *, backend: str | None = None,
     if b == "xla":
         q, s = _quantize_ref(lr, br, float(qmax))
     else:
-        q, s = quantize_delta_pallas(lr, br, block_rows=_block_rows(lr.shape[0]),
-                                     interpret=(b == "pallas_interpret"),
-                                     qmax=float(qmax))
+        q, s = _pallas_rows(quantize_delta_pallas, lr, br,
+                            interpret=(b == "pallas_interpret"),
+                            qmax=float(qmax))
     return q, s, n
 
 
@@ -224,9 +233,8 @@ def _apply_wire(value, q, scales, backend: str | None):
     if b == "xla":
         out = _apply_ref(gr, q, scales)
     else:
-        out = apply_delta_pallas(gr, q, scales,
-                                 block_rows=_block_rows(gr.shape[0]),
-                                 interpret=(b == "pallas_interpret"))
+        out = _pallas_rows(apply_delta_pallas, gr, q, scales,
+                           interpret=(b == "pallas_interpret"))
     return out.reshape(-1)[:n].reshape(shape).astype(dtype)
 
 
@@ -264,6 +272,6 @@ def push(local, base, global_val, *, backend: str | None = None):
     if b == "xla":
         out = _push_ref(lr, br, gr)
     else:
-        out = push_pallas(lr, br, gr, block_rows=_block_rows(lr.shape[0]),
-                          interpret=(b == "pallas_interpret"))
+        out = _pallas_rows(push_pallas, lr, br, gr,
+                           interpret=(b == "pallas_interpret"))
     return out.reshape(-1)[:n].reshape(shape).astype(dtype)

@@ -16,11 +16,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import get_config, get_shape, smoke_config
-from repro.configs.base import ShapeConfig
-from repro.distributed.sharding import ShardingRules
-from repro.launch.mesh import make_production_mesh
-from repro.launch.steps import make_prefill_step, make_serve_step
+from repro.configs import get_config, smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import ExecConfig, build_model
 from repro.telemetry import clock as tclock
 from repro.telemetry import metrics as tmetrics
@@ -144,7 +141,11 @@ def run_faasm_fanout(model, params, vocab_size: int, n_requests: int,
     spill-to-peer, and an end-to-end deadline stamped on every request.
     Requests refused everywhere settle with ``SHED_RC``; requests whose
     deadline expires settle with ``overload.DEADLINE_RC``.  Both are
-    reported in the returned dict instead of inflating the latency tail."""
+    reported in the returned dict instead of inflating the latency tail.
+
+    Per request ``i`` the dict also carries ``codes[i]``, ``prompts[i]``
+    (int32 token ids) and ``tokens[i]``, the greedy next token the call
+    returned (``None`` when it was not served)."""
     from repro import overload as oload
     from repro.core import FaasmRuntime
     from repro.state.ddo import VectorAsync
@@ -202,7 +203,12 @@ def run_faasm_fanout(model, params, vocab_size: int, n_requests: int,
                "p50_ms": hist.percentile(0.50) if served else 0.0,
                "p99_ms": hist.percentile(0.99) if served else 0.0,
                "degraded": wave["degraded"], "shed": n_shed,
-               "deadline_expired": n_deadline}
+               "deadline_expired": n_deadline,
+               "codes": wave["codes"],
+               "prompts": [np.frombuffer(p, np.int32) for p in payloads],
+               "tokens": [int(np.frombuffer(rt.output(c), np.int32)[0])
+                          if c is not None and r == 0 else None
+                          for c, r in zip(wave["call_ids"], wave["codes"])]}
         if state_wire is not None:
             out["state_wire"] = state_wire
             out["state_push_mb"] = sum(
@@ -212,6 +218,48 @@ def run_faasm_fanout(model, params, vocab_size: int, n_requests: int,
         rt.shutdown()
 
 
+def generate(model, params, tokens, new_tokens: int, extra=None):
+    """Greedy serving outside the runtime: one prefill over ``tokens``
+    (B, S) and ``new_tokens - 1`` decode steps through the KV cache.
+
+    Returns the generated ids (B, new_tokens) as numpy and the logits
+    (B, vocab) that chose the last of them.  Prefill and decode wall times
+    land in the ``faasm_serve_prefill_ms`` / ``faasm_serve_decode_ms``
+    histograms (and spans, when tracing is on)."""
+    cfg = model.cfg
+    B, S = tokens.shape
+    n_prefix = cfg.n_image_tokens if cfg.family == "vlm" else 0
+    prefill = jax.jit(model.prefill)
+    decode = jax.jit(model.decode_step)
+
+    reg = tmetrics.registry()
+    cache = model.init_cache(B, S + new_tokens + n_prefix)
+    tel = tspans.tracer()
+    t0 = tclock.now()
+    logits, cache, n = prefill(params, tokens, cache, extra)
+    tok = jnp.argmax(logits, -1).astype(jnp.int32)
+    t1 = tclock.now()
+    reg.histogram("faasm_serve_prefill_ms").observe((t1 - t0) * 1e3)
+    if tel is not None:
+        tel.record("serve.prefill", "serve", t0, t1, arch=cfg.name, tokens=S)
+    n_total = int(n) if not hasattr(n, "shape") else S + n_prefix
+
+    out = [tok]
+    t0 = tclock.now()
+    for i in range(new_tokens - 1):
+        idx = jnp.full((B,), n_total + i, jnp.int32)
+        logits, cache = decode(params, tok, cache, idx)
+        tok = jnp.argmax(logits, -1).astype(jnp.int32)
+        out.append(tok)
+    jax.block_until_ready(tok)
+    t1 = tclock.now()
+    reg.histogram("faasm_serve_decode_ms").observe((t1 - t0) * 1e3)
+    if tel is not None:
+        tel.record("serve.decode", "serve", t0, t1, arch=cfg.name,
+                   steps=new_tokens - 1)
+    return np.stack([np.asarray(t) for t in out], axis=1), logits
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b")
@@ -219,7 +267,6 @@ def main():
     ap.add_argument("--batch", type=int, default=2)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--new-tokens", type=int, default=16)
-    ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--faasm-requests", type=int, default=0,
                     help="also fan out N requests through the FAASM runtime "
                          "(invoke_many/wait_all batch path)")
@@ -244,6 +291,7 @@ def main():
                     help="expose the telemetry registry as Prometheus text "
                          "on this port (0 = off)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     reg = tmetrics.registry()
     if args.metrics_port:
@@ -260,11 +308,8 @@ def main():
     params = model.init(jax.random.PRNGKey(0))
 
     B, S = args.batch, args.prompt_len
-    max_len = S + args.new_tokens + (cfg.n_image_tokens
-                                     if cfg.family == "vlm" else 0)
     rng = np.random.default_rng(0)
-    St = S
-    tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, St)), jnp.int32)
+    tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, S)), jnp.int32)
     extra = None
     if cfg.family == "vlm":
         extra = jnp.asarray(rng.normal(size=(B, cfg.n_image_tokens,
@@ -273,36 +318,8 @@ def main():
         extra = jnp.asarray(rng.normal(size=(B, cfg.n_frames, cfg.d_model)),
                             jnp.bfloat16)
 
-    prefill = jax.jit(model.prefill)
-    decode = jax.jit(model.decode_step)
-
-    cache = model.init_cache(B, max_len)
-    tel = tspans.tracer()
-    t0 = tclock.now()
-    logits, cache, n = prefill(params, tokens, cache, extra)
-    tok = jnp.argmax(logits, -1).astype(jnp.int32)
-    t1 = tclock.now()
-    reg.histogram("faasm_serve_prefill_ms").observe((t1 - t0) * 1e3)
-    if tel is not None:
-        tel.record("serve.prefill", "serve", t0, t1, arch=cfg.name, tokens=S)
-    n_total = int(n) if not hasattr(n, "shape") else S + (
-        cfg.n_image_tokens if cfg.family == "vlm" else 0)
-
-    out = [tok]
-    t0 = tclock.now()
-    for i in range(args.new_tokens - 1):
-        idx = jnp.full((B,), n_total + i, jnp.int32)
-        logits, cache = decode(params, tok, cache, idx)
-        tok = jnp.argmax(logits, -1).astype(jnp.int32)
-        out.append(tok)
-    jax.block_until_ready(tok)
-    t1 = tclock.now()
-    reg.histogram("faasm_serve_decode_ms").observe((t1 - t0) * 1e3)
-    if tel is not None:
-        tel.record("serve.decode", "serve", t0, t1, arch=cfg.name,
-                   steps=args.new_tokens - 1)
-    gen = np.stack([np.asarray(t) for t in out], axis=1)
-    # the printed line reads the registry — the timers above are its only
+    gen, _ = generate(model, params, tokens, args.new_tokens, extra)
+    # the printed line reads the registry — generate()'s timers are its only
     # writers, so the log and /metrics can never disagree
     snap = reg.snapshot()
     prefill_s = snap["faasm_serve_prefill_ms_sum"] / 1e3

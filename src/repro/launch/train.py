@@ -17,6 +17,7 @@ from repro.configs import get_config, get_shape, smoke_config, smoke_shape
 from repro.configs.base import ShapeConfig
 from repro.data import PipelineConfig, make_batch
 from repro.distributed.sharding import ShardingRules
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import make_train_step
 from repro.models import ExecConfig, build_model
@@ -40,6 +41,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         cfg = smoke_config(args.arch)
