@@ -71,34 +71,37 @@ def check_analysis_entry_points() -> int:
               f"repro/state + repro/cancellation depend on these; fix "
               f"src/repro/analysis/ before trusting the tier-1 gate.")
         return 1
-    return check_telemetry_entry_points()
+    return check_overload_entry_points()
 
 
 def check_telemetry_entry_points() -> int:
     """The tracing plane must compile out when disarmed (one pointer
     compare per hook site, zero ring writes) and install/uninstall into
     every instrumented module — the bench_dispatch warm-p99 budget
-    depends on the disarmed fast path staying free."""
+    depends on the disarmed fast path staying free.  Runs after the
+    jax-free wire check: arming imports jax for the profiler mirroring
+    and the compile listener."""
     try:
         from repro import faults, telemetry
         from repro.analysis import sanitizer
         from repro.core import runtime
+        from repro.launch import serve
         from repro.state import kv, local
         from repro.telemetry import metrics, spans
 
         # disarmed: every hook slot is None — hook sites cost one compare
         assert not telemetry.enabled()
-        for mod in (runtime, kv, local, faults):
+        for mod in (runtime, kv, local, faults, serve):
             assert mod._TEL is None, mod
         # armed: one Tracer lands in every slot; disarm restores None
         t = telemetry.enable()
         try:
-            for mod in (runtime, kv, local, faults):
+            for mod in (runtime, kv, local, faults, serve):
                 assert mod._TEL is t, mod
             assert telemetry.tracer() is t
         finally:
             telemetry.disable()
-        for mod in (runtime, kv, local, faults):
+        for mod in (runtime, kv, local, faults, serve):
             assert mod._TEL is None, mod
         # compile-out: building + exercising a fabric while disarmed must
         # leave a fresh tracer's write counter untouched
@@ -126,7 +129,7 @@ def check_telemetry_entry_points() -> int:
               f"metrics registry depend on these; fix src/repro/telemetry/ "
               f"before trusting the tier-1 gate.")
         return 1
-    return check_overload_entry_points()
+    return 0
 
 
 def check_overload_entry_points() -> int:
@@ -256,7 +259,7 @@ def check_wire_entry_points() -> int:
               f"src/repro/kernels/state_push/hostcodec.py and "
               f"src/repro/state/wire.py before trusting the tier-1 gate.")
         return 1
-    return 0
+    return check_telemetry_entry_points()
 
 
 def main() -> int:

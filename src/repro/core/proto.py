@@ -137,31 +137,17 @@ class ExecutableCache:
     def __init__(self):
         self._cache: Dict[Tuple, Any] = {}
         self._lock = threading.Lock()
-        self.hits = 0
-        self.misses = 0
-        self.compile_seconds = 0.0
 
     def get_or_build(self, key: Tuple, build: Callable[[], Any]):
-        """Returns (executable, was_hit, seconds_spent)."""
+        """Returns (executable, was_hit, seconds_spent).  The seconds are
+        the whole of ``build``, its warm-up run included; the compiles
+        themselves are ``jax.compile`` spans while tracing is armed."""
         with self._lock:
             if key in self._cache:
-                self.hits += 1
                 return self._cache[key], True, 0.0
         t0 = tclock.now()
         built = build()
         dt = tclock.now() - t0
         with self._lock:
             self._cache.setdefault(key, built)
-            self.misses += 1
-            self.compile_seconds += dt
         return built, False, dt
-
-    def contains(self, key: Tuple) -> bool:
-        with self._lock:
-            return key in self._cache
-
-    def stats(self) -> dict:
-        with self._lock:
-            return {"hits": self.hits, "misses": self.misses,
-                    "entries": len(self._cache),
-                    "compile_seconds": self.compile_seconds}

@@ -413,6 +413,8 @@ class Host:
             tel.record("call.restore", "call", call.t_start, tclock.now(),
                        fn=call.fn, cold=cold)
         api = FaasmAPI(faaslet, self, rt, call)
+        span = (tel.begin("call.exec", "call", fn=call.fn)
+                if tel is not None else None)
         t0 = tclock.now()
         faults.point("slow-host", call=call.id, host=self.id)
         # arm the time-sliced cancel checkpoint: kernel dispatch wrappers
@@ -449,8 +451,7 @@ class Host:
             cancellation.clear()                 # executor thread is reused
         t_end = tclock.now()
         if tel is not None:
-            tel.record("call.exec", "call", t0, t_end, fn=call.fn,
-                       status=status, rc=rc, cold=cold)
+            tel.end(span, status=status, rc=rc, cold=cold)
         dur = t_end - t0
         faaslet.usage.charge_cpu(int(dur * 1e9))
         faaslet.calls_served += 1

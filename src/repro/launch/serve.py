@@ -23,6 +23,10 @@ from repro.telemetry import clock as tclock
 from repro.telemetry import metrics as tmetrics
 from repro.telemetry import spans as tspans
 
+# telemetry hook slot, installed by repro.telemetry.spans._install: while
+# disarmed the ``infer`` body's spans cost a pointer compare per boundary
+_TEL = None
+
 
 def make_infer_function(model, treedef, host_leaves, prompt_len: int = 16,
                         cache_key=("serve", "fwd"), state_wire: str = None):
@@ -53,15 +57,29 @@ def make_infer_function(model, treedef, host_leaves, prompt_len: int = 16,
         api.runtime.exec_cache.get_or_build(cache_key, _build_fwd)
         return {"params": host_leaves}
 
+    nbytes, leaves = sum(x.nbytes for x in host_leaves), len(host_leaves)
+
     def infer(api):
+        tel = _TEL
         state = api.host.user_state(api.faaslet)
         fwd, _, _ = api.runtime.exec_cache.get_or_build(cache_key, _build_fwd)
-        p = jax.tree_util.tree_unflatten(
-            treedef, [jnp.asarray(x) for x in state["params"]])
         tokens = np.frombuffer(api.read_call_input(),
                                np.int32).reshape(1, -1)
+        # serve.weights: the snapshot's leaves enqueued onto the device
+        span = (tel.begin("serve.weights", "serve", nbytes=nbytes,
+                          leaves=leaves)
+                if tel is not None else None)
+        p = jax.tree_util.tree_unflatten(
+            treedef, [jnp.asarray(x) for x in state["params"]])
+        # serve.forward: dispatch until the token is on the host, so it
+        # holds the wait for the weights' copies and the forward itself
+        if span is not None:
+            tel.end(span)
+            span = tel.begin("serve.forward", "serve")
         logits = fwd(p, jnp.asarray(tokens))
         tok = int(np.asarray(jnp.argmax(logits[0, -1])))
+        if span is not None:
+            tel.end(span)
         if state_wire is not None:
             from repro.state.ddo import VectorAsync
             stats = VectorAsync(api, "serve/stats")

@@ -721,7 +721,7 @@ class GlobalTier:
         tel = _TEL
         cost = _wire_mod._COST
         timed = tel is not None or cost is not None
-        t0 = tel.now() if tel is not None else 0.0
+        span = tel.begin("wire.pull", "wire") if tel is not None else None
         w0 = _clock.now_ns() if timed else 0
         numel = max(f.numel for f in served)
         delta = np.zeros(numel, np.float32)
@@ -748,11 +748,11 @@ class GlobalTier:
             cost.observe(frame.wire, frame.numel * 4, enc_ns,
                          wall_ns=_clock.now_ns() - w0)
         if tel is not None:
-            tel.record("wire.pull", "wire", t0, tel.now(), key=key,
-                       wire=frame.wire, nbytes=frame.nbytes,
-                       numel=frame.numel, encode_ns=enc_ns,
-                       prev_version=base_version, version=cur,
-                       frames=len(served), puller=host)
+            tel.end(span, key=key,
+                    wire=frame.wire, nbytes=frame.nbytes,
+                    numel=frame.numel, encode_ns=enc_ns,
+                    prev_version=base_version, version=cur,
+                    frames=len(served), puller=host)
         return frame, cur, new_residual
 
     def register_puller(self, key: str, origin: str) -> None:

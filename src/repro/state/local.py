@@ -462,6 +462,17 @@ class LocalTier:
 
     # -- pull / push (tier synchronisation) ----------------------------------------
 
+    @staticmethod
+    def _acquire_write(r: Replica, key: str, site: str) -> None:
+        """Take the replica write lock, under a ``state.lock`` span while
+        traced: the wait behind a push that holds it through its encode."""
+        tel = _TEL
+        span = (tel.begin("state.lock", "state", key=key, site=site)
+                if tel is not None else None)
+        r.lock.acquire_write()
+        if span is not None:
+            tel.end(span)
+
     def pull(self, key: str, *, wire: Optional[str] = None,
              backend: Optional[str] = None) -> int:
         """Ensure the replica holds the current global value.  Returns bytes
@@ -480,7 +491,7 @@ class LocalTier:
         size = self.global_tier.size(key)
         r = self.replica(key, size)
         moved = 0
-        r.lock.acquire_write()
+        self._acquire_write(r, key, "pull")
         try:
             if not r.full:
                 moved = self._full_pull_locked(key, r, size)
@@ -734,7 +745,7 @@ class LocalTier:
         a lost update.  ``pull_state(track_delta=True)`` on a warm shared
         replica uses this arm-only mode."""
         r = self._replicas[key]
-        r.lock.acquire_write()
+        self._acquire_write(r, key, "base")
         try:
             if force or r.base is None or r.base.size != r.buf.size:
                 self._refresh_base(r)
@@ -821,7 +832,7 @@ class LocalTier:
         the frame path."""
         gt = self.global_tier
         tel = _TEL
-        t0 = tel.now() if tel is not None else 0.0
+        span = tel.begin("wire.push", "wire") if tel is not None else None
         r.lock.acquire_write()
         try:
             local = r.buf.view(dt)
@@ -839,9 +850,9 @@ class LocalTier:
             if res is None:              # fenced out: superseded/duplicate
                 self._resync_locked(key, r)
                 if tel is not None:
-                    tel.record("wire.push", "wire", t0, tel.now(), key=key,
-                               wire="inplace", nbytes=0, fenced=True,
-                               origin=self.origin_id)
+                    tel.end(span, key=key,
+                            wire="inplace", nbytes=0, fenced=True,
+                            origin=self.origin_id)
                 return 0
             moved, prev, new = res
             if not rebased:
@@ -855,10 +866,10 @@ class LocalTier:
             if r.global_version == prev:
                 r.global_version = new
             if tel is not None:
-                tel.record("wire.push", "wire", t0, tel.now(), key=key,
-                           wire="inplace", nbytes=moved, encode_ns=0,
-                           prev_version=prev, version=new,
-                           origin=self.origin_id)
+                tel.end(span, key=key,
+                        wire="inplace", nbytes=moved, encode_ns=0,
+                        prev_version=prev, version=new,
+                        origin=self.origin_id)
             return moved
         finally:
             r.lock.release_write()
@@ -883,7 +894,7 @@ class LocalTier:
         tel = _TEL
         cost = _wire_mod._COST
         timed = tel is not None or cost is not None
-        t0 = tel.now() if tel is not None else 0.0
+        span = tel.begin("wire.push", "wire") if tel is not None else None
         enc0 = _clock.now_ns() if timed else 0
         r.lock.acquire_write()
         try:
@@ -943,20 +954,20 @@ class LocalTier:
             finally:
                 r.lock.release_write()
             if tel is not None:
-                tel.record("wire.push", "wire", t0, tel.now(), key=key,
-                           wire=frame.wire, nbytes=0, fenced=True,
-                           encode_ns=enc_ns, origin=self.origin_id)
+                tel.end(span, key=key,
+                        wire=frame.wire, nbytes=0, fenced=True,
+                        encode_ns=enc_ns, origin=self.origin_id)
             return 0
         self._after_push(key, r, frame)
         if cost is not None:
             cost.observe(frame.wire, frame.numel * 4, enc_ns,
                          wall_ns=_clock.now_ns() - enc0)
         if tel is not None:
-            tel.record("wire.push", "wire", t0, tel.now(), key=key,
-                       wire=frame.wire, nbytes=frame.nbytes,
-                       numel=frame.numel, encode_ns=enc_ns,
-                       prev_version=frame.prev_version,
-                       version=frame.version, origin=self.origin_id)
+            tel.end(span, key=key,
+                    wire=frame.wire, nbytes=frame.nbytes,
+                    numel=frame.numel, encode_ns=enc_ns,
+                    prev_version=frame.prev_version,
+                    version=frame.version, origin=self.origin_id)
         if auto:
             # adaptive feedback only when the policy made the choice: forced
             # pushes skip the two extra full-array metric passes
@@ -984,7 +995,7 @@ class LocalTier:
         tel = _TEL
         cost = _wire_mod._COST
         timed = tel is not None or cost is not None
-        t0 = tel.now() if tel is not None else 0.0
+        span = tel.begin("wire.push", "wire") if tel is not None else None
         enc0 = _clock.now_ns() if timed else 0
         r.lock.acquire_write()
         try:
@@ -1009,10 +1020,8 @@ class LocalTier:
                 # codec.encode materialises the frame (np.asarray blocks on
                 # the dispatched kernels), so nothing in flight still reads
                 # r.base when _refresh_base mutates it below
-                try:
-                    frame, residual = codec.encode(eff, base, backend=backend)
-                except Exception as e:
-                    raise CodecFallback(e) from e
+                frame, residual = self._encode(tel, codec, key, eff, base,
+                                               backend)
                 d.residual = residual
                 d.base = local               # device snapshot: a rebind
                 # d.value mirrors the host buffer only when no device-side
@@ -1029,10 +1038,8 @@ class LocalTier:
                     r.residual = np.zeros(local.size, np.float32)
                 snap = local.astype(np.float32)  # one coherent buffer read
                 eff = snap + r.residual
-                try:
-                    frame, residual = codec.encode(eff, base, backend=backend)
-                except Exception as e:
-                    raise CodecFallback(e) from e
+                frame, residual = self._encode(tel, codec, key, eff, base,
+                                               backend)
                 # owned writable copy: np.asarray of a jax array is read-only
                 # and would alias the device buffer
                 r.residual = np.array(residual, dtype=np.float32)
@@ -1061,20 +1068,20 @@ class LocalTier:
             finally:
                 r.lock.release_write()
             if tel is not None:
-                tel.record("wire.push", "wire", t0, tel.now(), key=key,
-                           wire=frame.wire, nbytes=0, fenced=True,
-                           encode_ns=enc_ns, origin=self.origin_id)
+                tel.end(span, key=key,
+                        wire=frame.wire, nbytes=0, fenced=True,
+                        encode_ns=enc_ns, origin=self.origin_id)
             return 0
         self._after_push(key, r, frame)
         if cost is not None:
             cost.observe(frame.wire, frame.numel * 4, enc_ns,
                          wall_ns=_clock.now_ns() - enc0)
         if tel is not None:
-            tel.record("wire.push", "wire", t0, tel.now(), key=key,
-                       wire=frame.wire, nbytes=frame.nbytes,
-                       numel=frame.numel, encode_ns=enc_ns,
-                       prev_version=frame.prev_version,
-                       version=frame.version, origin=self.origin_id)
+            tel.end(span, key=key,
+                    wire=frame.wire, nbytes=frame.nbytes,
+                    numel=frame.numel, encode_ns=enc_ns,
+                    prev_version=frame.prev_version,
+                    version=frame.version, origin=self.origin_id)
         if auto:
             # adaptive feedback (policy-chosen pushes only): what the
             # quantisation dropped vs what it carried.  Carried mass is
@@ -1091,6 +1098,21 @@ class LocalTier:
                 residual_ratio=_mean_abs(residual) / (carried + 1e-12),
                 wire=frame.wire)
         return moved
+
+    @staticmethod
+    def _encode(tel, codec, key: str, eff, base, backend: Optional[str]):
+        """The quantised push's codec encode, under a ``wire.encode`` span
+        while traced (a fresh shape compiles here); any failure becomes a
+        :class:`CodecFallback`."""
+        span = (tel.begin("wire.encode", "wire", key=key, wire=codec.name)
+                if tel is not None else None)
+        try:
+            return codec.encode(eff, base, backend=backend)
+        except Exception as e:
+            raise CodecFallback(e) from e
+        finally:
+            if span is not None:
+                tel.end(span)
 
     def _after_push(self, key: str, r: Replica, frame: WireFrame) -> None:
         """Post-apply bookkeeping: advance the replica's global base version

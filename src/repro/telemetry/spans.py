@@ -33,9 +33,24 @@ the **primary's** ``fence_id`` with distinct epochs, their spans land as
 siblings of one logical call in the export: group by ``fence``, order by
 ``epoch``.
 
-Import-light on purpose (stdlib only): ``repro.core``/``repro.state``
-hold a ``_TEL`` slot this module installs into; it must never import
-them back at top level (:func:`_install` does, lazily).
+One clock with the device trace
+-------------------------------
+
+An interval whose start and end one thread passes through is opened with
+:meth:`Tracer.begin` and closed with :meth:`Tracer.end`.  An armed
+tracer (:func:`enable`) also enters each of them as a
+``jax.profiler.TraceAnnotation`` of the same name carrying ``call=<id>``,
+so a profiler session started around the workload holds the program's
+spans and the device's operations on one clock.  Spans stamped after the
+fact (:meth:`Tracer.record`: ``call.queue``, ``jax.compile``) stay in the
+ring only.  While armed, a JAX event-duration listener turns every backend
+compile into a ``jax.compile`` span on the compiling thread, tagged with
+the compiled function's ``fun_name``; :func:`disable` unregisters it.
+
+Import-light on purpose (stdlib only): ``repro.core``/``repro.state``/
+``repro.launch.serve`` hold a ``_TEL`` slot this module installs into; it
+must never import them, or ``jax``, at top level (:func:`_install` and
+:func:`enable` do, lazily).
 """
 from __future__ import annotations
 
@@ -50,6 +65,7 @@ __all__ = [
 
 _RING_CAPACITY = 8192            # spans per thread before drop-oldest
 _COLLECTED_CAP = 1 << 20         # collector hard cap (runaway guard)
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 # Sanitizer hook: repro.analysis.sanitizer._install points this at its
 # drain guard; Tracer.drain calls it so a collector drain under a
@@ -119,6 +135,21 @@ class _Ring:
         return out
 
 
+class _Open:
+    """An interval span between :meth:`Tracer.begin` and :meth:`Tracer.end`,
+    with the profiler annotation it holds open (``None`` unmirrored)."""
+
+    __slots__ = ("name", "cat", "t0", "tags", "annotation")
+
+    def __init__(self, name: str, cat: str, t0: float,
+                 tags: Dict[str, Any], annotation: Any):
+        self.name = name
+        self.cat = cat
+        self.t0 = t0
+        self.tags = tags
+        self.annotation = annotation
+
+
 class _Ctx:
     __slots__ = ("call", "fence", "epoch", "host")
 
@@ -132,7 +163,10 @@ class _Ctx:
 class Tracer:
     """The armed tracing state: ring registry + collector + counters."""
 
-    def __init__(self):
+    def __init__(self, annotation=None):
+        # ``jax.profiler.TraceAnnotation`` when begin/end spans are mirrored
+        # into the profiler's trace (set by :func:`enable`), else None
+        self._annotation = annotation
         self._mu = threading.Lock()          # ring registry + collected list
         self._tls = threading.local()
         self._rings: Dict[int, Tuple[str, _Ring]] = {}
@@ -202,6 +236,42 @@ class Tracer:
         t = clock.now()
         self.record(name, cat, t, t, **tags)
 
+    def begin(self, name: str, cat: str, **tags: Any) -> _Open:
+        """Open an interval span on this thread; :meth:`end` closes and
+        records it on the same thread.  Mirrored, it also enters a
+        profiler annotation of the same name with the context's call id.
+        A span never ended (an exception between the two) records nothing;
+        its annotation closes when the handle is freed."""
+        annotation = self._annotation
+        if annotation is not None:
+            ctx = self._ctx()
+            call = ctx.call if ctx is not None else None
+            annotation = (annotation(name) if call is None
+                          else annotation(name, call=call))
+        t0 = clock.now()
+        if annotation is not None:
+            annotation.__enter__()
+        return _Open(name, cat, t0, tags, annotation)
+
+    def end(self, span: _Open, **tags: Any) -> None:
+        """Close ``span`` (from :meth:`begin`) and record it, with ``tags``
+        added to those given at its start."""
+        if span.annotation is not None:
+            span.annotation.__exit__(None, None, None)
+        t1 = clock.now()
+        if tags:
+            span.tags.update(tags)
+        self.record(span.name, span.cat, span.t0, t1, **span.tags)
+
+    def compiled(self, event: str, duration: float, **kw: Any) -> None:
+        """JAX event-duration listener: a backend compile that just ended
+        becomes a ``jax.compile`` span on the compiling thread, so it
+        carries that thread's call context."""
+        if event == COMPILE_EVENT:
+            t1 = clock.now()
+            self.record("jax.compile", "jax", t1 - duration, t1,
+                        fun_name=kw.get("fun_name"))
+
     # -- collector (never call under a stripe/key lock) ---------------------
 
     def drain(self) -> List[Span]:
@@ -255,20 +325,26 @@ def _install(t: Optional[Tracer]) -> None:
     slots.  Imports live here, lazily, to keep this module import-light."""
     from repro import faults
     from repro.core import runtime
+    from repro.launch import serve
     from repro.state import kv, local
     runtime._TEL = t
     kv._TEL = t
     local._TEL = t
     faults._TEL = t
+    serve._TEL = t
 
 
 def enable() -> Tracer:
     """Arm tracing (idempotent).  Hook sites go live immediately; spans
-    from calls already in flight pick up mid-lifecycle."""
+    from calls already in flight pick up mid-lifecycle.  Begin/end spans
+    are mirrored into the profiler's trace, and a compile listener is
+    registered with JAX until :func:`disable`."""
     global _active
     if _active is None:
-        _active = Tracer()
+        import jax
+        _active = Tracer(annotation=jax.profiler.TraceAnnotation)
         _install(_active)
+        jax.monitoring.register_event_duration_secs_listener(_active.compiled)
     return _active
 
 
@@ -276,5 +352,7 @@ def disable() -> None:
     global _active
     if _active is None:
         return
+    import jax
+    jax.monitoring.unregister_event_duration_listener(_active.compiled)
     _active = None
     _install(None)

@@ -12,10 +12,16 @@ Covers the observability contract in ``docs/observability.md``:
     well-formed trace
   * sanitizer integration — collector drain under a stripe/key lock is
     reported, ring writes under the same lock are not
+  * the served ``infer`` body — ``serve.weights``/``serve.forward``/
+    ``wire.encode`` nest under their call, a compile inside a call is a
+    ``jax.compile`` span, and the spans mirrored into a profiler session
+    land on the harness's clock join within 1 ms
 """
 import json
+import sys
 import threading
 import time
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -47,18 +53,63 @@ def _spans_named(span_list, name):
     return [s for s in span_list if s.name == name]
 
 
+def _compile_listeners():
+    """Tracers' compile listeners in JAX's (private) listener registry."""
+    from jax._src import monitoring
+    return [cb for cb in monitoring._event_duration_secs_listeners
+            if getattr(cb, "__func__", None) is spans.Tracer.compiled]
+
+
+@pytest.fixture(scope="module")
+def tiny_lm():
+    """A small qwen-shaped model whose ``serve/stats`` vector is large
+    enough to take the int8 wire."""
+    import jax
+    from repro.configs import smoke_config
+    from repro.models import ExecConfig, build_model
+    cfg = smoke_config("qwen1.5-0.5b").with_overrides(vocab_size=2048)
+    model = build_model(cfg, ExecConfig(backend="xla", loss_chunk=0))
+    flat, treedef = jax.tree_util.tree_flatten(
+        model.init(jax.random.PRNGKey(0)))
+    return model, treedef, [np.asarray(x) for x in flat], cfg.vocab_size
+
+
+def _serve(lm, n=2, prompt_len=8):
+    """``n`` served ``infer`` calls (int8 ``serve/stats`` push) on a fresh
+    runtime; returns their call ids."""
+    from repro.launch.serve import make_infer_function
+    model, treedef, leaves, vocab = lm
+    rt = FaasmRuntime(n_hosts=1, capacity=2)
+    try:
+        VectorAsync.create(rt.global_tier, "serve/stats",
+                           np.zeros(vocab, np.float32))
+        rt.upload(make_infer_function(model, treedef, leaves,
+                                      prompt_len=prompt_len,
+                                      state_wire="int8"))
+        prompt = np.arange(prompt_len, dtype=np.int32).tobytes()
+        cids = rt.invoke_many("infer", [prompt] * n)
+        assert rt.wait_all(cids, timeout=120) == [0] * n
+        return cids
+    finally:
+        rt.shutdown()
+
+
 # -- compile-out --------------------------------------------------------------
 
-def test_disarmed_hooks_compile_out():
-    """Disarmed, every hook slot is None and a full runtime + fabric
-    workload performs zero ring-buffer writes."""
+def test_disarmed_hooks_compile_out(tiny_lm):
+    """Disarmed, every hook slot is None, no compile listener is
+    registered, and a full runtime + fabric workload — a served ``infer``
+    call with its int8 push among it — performs zero ring-buffer writes."""
     from repro.core import runtime as runtime_mod
+    from repro.launch import serve as serve_mod
     from repro.state import kv as kv_mod
     from repro.state import local as local_mod
 
     assert not telemetry.enabled()
-    for mod in (runtime_mod, kv_mod, local_mod, faults):
+    for mod in (runtime_mod, kv_mod, local_mod, faults, serve_mod):
         assert mod._TEL is None
+    assert _compile_listeners() == []
+    _serve(tiny_lm, n=1)
 
     gt, t = _fabric()
     t.replica(KEY).buf.view(np.float32)[0] += 1.0
@@ -76,20 +127,24 @@ def test_disarmed_hooks_compile_out():
     tr = telemetry.enable()
     assert tr.writes == 0
     assert tr.spans() == []
+    telemetry.disable()
 
 
 def test_enable_disable_installs_hooks():
     from repro.core import runtime as runtime_mod
+    from repro.launch import serve as serve_mod
     from repro.state import kv as kv_mod
     from repro.state import local as local_mod
 
     t = telemetry.enable()
     assert telemetry.enable() is t               # idempotent
-    for mod in (runtime_mod, kv_mod, local_mod, faults):
+    for mod in (runtime_mod, kv_mod, local_mod, faults, serve_mod):
         assert mod._TEL is t
+    assert len(_compile_listeners()) == 1
     telemetry.disable()
-    for mod in (runtime_mod, kv_mod, local_mod, faults):
+    for mod in (runtime_mod, kv_mod, local_mod, faults, serve_mod):
         assert mod._TEL is None
+    assert _compile_listeners() == []
 
 
 def test_ring_drop_oldest():
@@ -268,6 +323,122 @@ def test_wire_span_tags():
     assert full and full[-1].tags["puller"] == "puller"
     assert full[-1].tags["nbytes"] > 0
     telemetry.disable()
+
+
+def _within(inner, outer):
+    return outer.t0 <= inner.t0 <= inner.t1 <= outer.t1
+
+
+def test_infer_body_spans_nest_under_their_call(tiny_lm):
+    """An armed ``infer`` call records the weights' enqueue, the forward,
+    the int8 encode and the replica-lock waits, each on the call's thread
+    with its id, inside ``call.exec`` (the encode inside ``wire.push``)."""
+    t = telemetry.enable()
+    try:
+        cids = _serve(tiny_lm, n=2)
+        got = t.spans()
+    finally:
+        telemetry.disable()
+    _, _, leaves, _ = tiny_lm
+    for cid in cids:
+        mine = [s for s in got if s.call == cid]
+        one = {name: _spans_named(mine, name) for name in (
+            "call.exec", "serve.weights", "serve.forward", "wire.push",
+            "wire.encode")}
+        assert all(len(v) == 1 for v in one.values()), one
+        one = {name: v[0] for name, v in one.items()}
+        ex = one["call.exec"]
+        for name in ("serve.weights", "serve.forward", "wire.push"):
+            assert _within(one[name], ex), name
+            assert one[name].thread == ex.thread
+        assert _within(one["wire.encode"], one["wire.push"])
+        assert one["serve.weights"].t1 <= one["serve.forward"].t0
+        assert one["serve.forward"].t1 <= one["wire.push"].t0
+        assert one["serve.weights"].tags == {
+            "nbytes": sum(x.nbytes for x in leaves), "leaves": len(leaves)}
+        assert one["wire.encode"].tags == {"key": "serve/stats",
+                                           "wire": "int8"}
+        # the waits for the replica lock in the stats pull and base snapshot
+        locks = _spans_named(mine, "state.lock")
+        assert {s.tags["site"] for s in locks} == {"pull", "base"}
+        assert all(_within(s, ex) for s in locks)
+
+
+def test_compile_inside_a_call_is_a_jax_compile_span():
+    """A backend compile inside a call becomes a ``jax.compile`` span with
+    the compiled function's name and the call's id; after ``disable()``
+    the listener is gone and a compile records nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    def recompile(api):
+        def fresh_fn(x):                 # a new function: a fresh compile
+            return x * 3.0 + 1.0
+        jax.jit(fresh_fn)(jnp.ones(5)).block_until_ready()
+        return 0
+
+    t = telemetry.enable()
+    rt = FaasmRuntime(n_hosts=1)
+    try:
+        rt.upload(FunctionDef("recompile", recompile))
+        cid = rt.invoke("recompile")
+        assert rt.wait(cid, timeout=60) == 0
+        comp = _spans_named(t.spans(), "jax.compile")
+    finally:
+        rt.shutdown()
+        telemetry.disable()
+    mine = [s for s in comp if s.call == cid]
+    assert any(s.tags["fun_name"] == "jit(fresh_fn)" for s in mine), comp
+    assert all(s.t1 > s.t0 for s in mine)
+    assert _compile_listeners() == []
+    writes = t.writes
+    jax.jit(lambda x: x - 2.0)(jnp.ones(6)).block_until_ready()
+    assert t.writes == writes
+
+
+# the interval spans a profiler session sees, each with its call's id
+MIRRORED = ("call.exec", "serve.weights", "serve.forward", "wire.push",
+            "wire.encode")
+
+
+def test_mirrored_spans_share_the_profiler_clock(tiny_lm, tmp_path):
+    """Under a CPU profiler session the armed tracer's interval spans
+    appear as host events of the same name carrying ``call=<id>``, and
+    the harness's one-marker join (``bench.window_mark``) puts both ends
+    of each within 1 ms of its ring span."""
+    import jax
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+    from bench import trace_reduce
+
+    t = telemetry.enable()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        mark = clock.now_ns()
+        with jax.profiler.TraceAnnotation(trace_reduce.MARK):
+            pass
+        cids = _serve(tiny_lm, n=2)
+    finally:
+        jax.profiler.stop_trace()
+        ring = {(s.name, s.call): s for s in t.spans() if s.name in MIRRORED}
+        telemetry.disable()
+    pd = trace_reduce.load(tmp_path)
+    off = trace_reduce.mark_ns(pd) - mark
+    seen = set()
+    for plane in pd.planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name not in MIRRORED:
+                    continue
+                assert plane.name.startswith("/host:"), plane.name
+                call = dict(e.stats)["call"]
+                s = ring[(e.name, int(call))]
+                assert abs(e.start_ns - (s.t0 * 1e9 + off)) < 1e6
+                assert abs(e.start_ns + e.duration_ns
+                           - (s.t1 * 1e9 + off)) < 1e6
+                seen.add((e.name, int(call)))
+    assert seen == {(name, cid) for name in MIRRORED for cid in cids}
 
 
 def test_fence_reject_instant():
