@@ -10,11 +10,18 @@ Two encode paths, chosen per call:
   numpy passes, so the JAX dispatch round-trip (a ~1.7 ms floor at 64 KB)
   is pure overhead and is skipped entirely.
 * **device**: anything holding a device array goes through **one** fused
-  jitted executable (flatten + pad + quantise + residual in a single
-  dispatch, cached by jax per ``(shape, dtype, qmax)`` and per backend), and
-  large values are encoded in row chunks whose copy-out is pipelined with
-  the next chunk's dispatch — async dispatch means chunk N quantises on
-  device while chunk N−1's payload is crossing to the host.
+  jitted executable per backend: on ``xla`` flatten + pad + quantise +
+  residual (``_encode_fused``), on Pallas flatten + pad + the quantise
+  kernel + the f32 delta rows (``_encode_pallas``).  jax compiles each once
+  per ``(shape, dtype, qmax)`` and caches it.  On ``xla`` large values are
+  encoded in row chunks whose copy-out is pipelined with the next chunk's
+  dispatch — async dispatch means chunk N quantises on device while chunk
+  N−1's payload is crossing to the host.
+
+Every Pallas dispatch here runs inside ``jax.jit`` with the kernel and its
+static parameters as static arguments.  An eager ``pallas_call`` builds a
+fresh wrapper on every call, so jax's cache never hits and each call pays
+a backend compile.
 """
 from __future__ import annotations
 
@@ -52,13 +59,16 @@ def _to_rows(x):
     return padded.reshape(rows, LANES), n
 
 
+@functools.partial(jax.jit, static_argnums=(0,),
+                   static_argnames=("interpret", "qmax"))
 def _pallas_rows(kernel, *operands, interpret: bool, **kw):
     """Run a row-streaming state-push kernel over (R, ·) operands.
 
     Blocks are (8k, 128) tiles of at most 256 rows, as Mosaic requires, so
     the rows are zero-padded to a whole number of blocks.  Zero rows
     quantise to zero codes and apply as a no-op; every output is trimmed
-    back to R rows."""
+    back to R rows.  jax compiles it once per kernel, operand shapes and
+    static parameters."""
     rows = operands[0].shape[0]
     blk = min(256, round_up(rows, 8))
     pad = round_up(rows, blk) - rows
@@ -93,29 +103,45 @@ def _encode_fp8_fused(local, base, with_residual):
     return q, s, resid
 
 
+@functools.partial(jax.jit,
+                   static_argnames=("qmax", "fp8", "interpret", "with_residual"))
+def _encode_pallas(local, base, qmax, fp8, interpret, with_residual):
+    """Pallas twin of :func:`_encode_fused`: flatten/pad and the quantise
+    kernel in one executable, returning the codes, the scales and (with
+    ``with_residual``) the f32 delta rows ``lr − br``.  The residual itself
+    is formed on the host from those rows, so it rounds as a plain
+    multiply-subtract."""
+    lr, _ = _to_rows(local)
+    br, _ = _to_rows(base)
+    if fp8:
+        q, s = _pallas_rows(quantize_fp8_pallas, lr, br, interpret=interpret)
+    else:
+        q, s = _pallas_rows(quantize_delta_pallas, lr, br,
+                            interpret=interpret, qmax=float(qmax))
+    if not with_residual:
+        return q, s
+    return q, s, lr - br
+
+
 def _device_encode(eff, base, *, qmax, fp8, b, with_residual):
     """Device-path encode returning host numpy wire buffers.
 
-    Values above ``DEVICE_CHUNK_ROWS`` rows are encoded chunk by chunk:
-    every chunk's kernel is dispatched before any copy-out blocks, so the
-    device quantises chunk N while chunk N−1 streams to the host.  Scales
-    are per-row and chunks split on row boundaries, so the result is
-    bitwise identical to a single-shot encode."""
+    Each backend takes one cached executable per shape: ``_encode_pallas``
+    on Pallas, ``_encode_fused`` on ``xla``.  On ``xla``, values above
+    ``DEVICE_CHUNK_ROWS`` rows are encoded chunk by chunk: every chunk's
+    kernel is dispatched before any copy-out blocks, so the device
+    quantises chunk N while chunk N−1 streams to the host.  Scales are
+    per-row and chunks split on row boundaries, so the result is bitwise
+    identical to a single-shot encode."""
     n = int(np.prod(np.shape(eff))) if np.shape(eff) else 1
     rows = hostcodec.rows_for(n)
     if b != "xla":
-        lr, _ = _to_rows(eff)
-        br, _ = _to_rows(base)
-        interp = b == "pallas_interpret"
-        if fp8:
-            q, s = _pallas_rows(quantize_fp8_pallas, lr, br, interpret=interp)
-        else:
-            q, s = _pallas_rows(quantize_delta_pallas, lr, br,
-                                interpret=interp, qmax=float(qmax))
-        qn, sn = np.asarray(q), np.asarray(s)
+        out = _encode_pallas(eff, base, float(qmax), fp8,
+                             b == "pallas_interpret", with_residual)
+        qn, sn = np.asarray(out[0]), np.asarray(out[1])
         if not with_residual:
             return qn, sn, n, None
-        deltar = np.asarray(lr - br)
+        deltar = np.asarray(out[2])
         resid = deltar - qn.astype(np.float32) * sn
         return qn, sn, n, resid.reshape(-1)[:n]
     if rows <= DEVICE_CHUNK_ROWS:

@@ -23,6 +23,7 @@ from repro.state.kv import GlobalTier
 from repro.state.local import LocalTier
 from repro.state.wire import (WireCostModel, WirePolicy, available_wires,
                               get_codec)
+from repro.telemetry.spans import COMPILE_EVENT
 
 BACKENDS = ("xla", "pallas_interpret")
 ODD_SIZES = (1, 5, 130, 1000, 4097)
@@ -138,7 +139,7 @@ def test_fp8_codec_registered_only_when_available():
 
 @pytest.mark.parametrize("backend", BACKENDS)
 @pytest.mark.parametrize("qmax", [127, 7])
-@pytest.mark.parametrize("n", [130, 1000])
+@pytest.mark.parametrize("n", [130, 1000, 151936])
 def test_quant_parity_host_vs_device(backend, qmax, n):
     """The device encode and the host fast path agree to quantisation
     precision (scales may differ by one ULP — see module docstring)."""
@@ -175,12 +176,14 @@ def test_fp8_parity_host_vs_device(backend, n):
     assert np.abs(deq_h - deq_d).max() <= bound
 
 
-@pytest.mark.parametrize("backend", BACKENDS)
-def test_residual_conservation_device_paths(backend):
+@pytest.mark.parametrize(
+    "backend,n", [(b, 1000) for b in BACKENDS] + [(b, 151936) for b in BACKENDS],
+    ids=list(BACKENDS) + [f"{b}-151936" for b in BACKENDS])
+def test_residual_conservation_device_paths(backend, n):
     """Fused device encode's residual also conserves: deq + resid == delta
     to f32 rounding."""
     import jax.numpy as jnp
-    eff, base = _pair(1000, seed=11)
+    eff, base = _pair(n, seed=11)
     q, s, n, resid = ops.encode_quant(jnp.asarray(eff), jnp.asarray(base),
                                       qmax=127, backend=backend)
     deq = hostcodec.decode_rows(np.asarray(q), np.asarray(s), n)
@@ -202,6 +205,79 @@ def test_device_chunked_encode_matches_single_shot():
     assert np.array_equal(s, np.asarray(ss))
     np.testing.assert_array_equal(resid,
                                   np.asarray(rs).reshape(-1)[:n])
+
+
+@pytest.mark.parametrize("qmax", [127, 7])
+@pytest.mark.parametrize("n", [1000, 151936])
+def test_pallas_encode_matches_eager_kernel_bitwise(n, qmax):
+    """The jitted Pallas encode returns the codes, scales and residual that
+    the quantise kernel gives when called eagerly on the padded rows."""
+    import jax.numpy as jnp
+    from repro.kernels.common import round_up
+    from repro.kernels.state_push.kernel import quantize_delta_pallas
+    eff, base = _pair(n, seed=n + qmax, scale=0.1)
+    je, jb = jnp.asarray(eff), jnp.asarray(base)
+    lr, _ = ops._to_rows(je)
+    br, _ = ops._to_rows(jb)
+    rows = lr.shape[0]
+    blk = min(256, round_up(rows, 8))
+    pad = ((0, round_up(rows, blk) - rows), (0, 0))
+    q, s = quantize_delta_pallas(jnp.pad(lr, pad), jnp.pad(br, pad),
+                                 block_rows=blk, interpret=True,
+                                 qmax=float(qmax))
+    qe, se = np.asarray(q)[:rows], np.asarray(s)[:rows]
+    re = (np.asarray(lr - br) - qe.astype(np.float32) * se).reshape(-1)[:n]
+    qj, sj, numel, rj = ops.encode_quant(je, jb, qmax=qmax,
+                                         backend="pallas_interpret")
+    assert numel == n and qj.dtype == np.int8
+    assert np.array_equal(qj, qe)
+    assert np.array_equal(sj.view(np.uint32), se.view(np.uint32))
+    assert np.array_equal(rj.view(np.uint32), re.view(np.uint32))
+
+
+_PI = "pallas_interpret"
+_PALLAS_OPS = {
+    "encode_quant_127": lambda e, b: ops.encode_quant(e, b, qmax=127,
+                                                      backend=_PI),
+    "encode_quant_7": lambda e, b: ops.encode_quant(e, b, qmax=7,
+                                                    backend=_PI),
+    "encode_fp8": lambda e, b: ops.encode_fp8(e, b, backend=_PI),
+    "quantize_delta": lambda e, b: ops.quantize_delta(e, b, backend=_PI),
+    "apply_delta": lambda e, b: ops.apply_delta(
+        b, *hostcodec.encode_quant(np.asarray(e), np.asarray(b))[:2],
+        backend=_PI),
+    "push": lambda e, b: ops.push(e, b, b, backend=_PI),
+}
+
+
+@pytest.mark.parametrize("op", sorted(_PALLAS_OPS))
+def test_pallas_interpret_dispatch_compiles_once_per_shape(op):
+    """A Pallas dispatch compiles on the first call of a shape and never
+    again for that shape; a new shape compiles anew."""
+    import jax
+    import jax.numpy as jnp
+
+    def call(n):
+        eff, base = _pair(n, seed=n)
+        jax.block_until_ready(_PALLAS_OPS[op](jnp.asarray(eff),
+                                              jnp.asarray(base)))
+
+    compiles = []
+
+    def listen(event, duration, **kw):
+        if event == COMPILE_EVENT:
+            compiles.append(kw.get("fun_name"))
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        call(1531)
+        compiles.clear()
+        call(1531)
+        assert compiles == []
+        call(2711)
+        assert compiles
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
 
 
 def test_host_fast_path_skips_jax_dispatch():

@@ -92,6 +92,14 @@ def _quantize(n):
             [((n,), F32), ((n,), F32)])
 
 
+def _encode(n):
+    """The served push's encode: one executable, delta rows included."""
+    return (lambda a, b: state_push._encode_pallas(
+                a, b, qmax=127.0, fp8=False, interpret=False,
+                with_residual=True),
+            [((n,), F32), ((n,), F32)])
+
+
 def _apply(n):
     rows = -(-n // 128)
     return (lambda g, q, s: state_push.apply_delta(g, q, s, backend="pallas"),
@@ -107,6 +115,7 @@ CASES = {
     "gmm-deepseek-moe-16b": lambda: _gmm("deepseek-moe-16b", 1024),
     "state_push-quantize-serve_stats": lambda: _quantize(SERVE_STATS_NUMEL),
     "state_push-apply-serve_stats": lambda: _apply(SERVE_STATS_NUMEL),
+    "state_push-encode-serve_stats": lambda: _encode(SERVE_STATS_NUMEL),
     "state_push-quantize-4MB": lambda: _quantize(FOUR_MB_NUMEL),
     "state_push-apply-4MB": lambda: _apply(FOUR_MB_NUMEL),
 }
