@@ -18,6 +18,21 @@ FAMILIES = ("dense", "moe", "ssm", "hybrid", "encdec", "vlm")
 
 
 @dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rope scaling (DeepSeek-V2's ``rope_scaling`` of type ``yarn``):
+    frequencies interpolated by ``factor`` outside the band that
+    ``beta_fast``/``beta_slow`` rotations keep within the original window,
+    and attention logits scaled by ``mscale``."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float
+    beta_slow: float
+    mscale: float
+    mscale_all_dim: float
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     """Architecture hyper-parameters.
 
@@ -49,6 +64,13 @@ class ModelConfig:
     rope_theta: float = 10_000.0
     use_rope: bool = True
     causal: bool = True
+    yarn: Optional[YarnScaling] = None
+
+    # --- latent attention (DeepSeek-V2 MLA; kv_lora_rank 0 = GQA) --------------
+    kv_lora_rank: int = 0              # width of the cached key/value latent
+    qk_nope_head_dim: int = 0          # per-head q/k channels without rope
+    qk_rope_head_dim: int = 0          # rope channels (one k_pe for all heads)
+    v_head_dim: int = 0
 
     # --- norms / MLP ----------------------------------------------------------
     norm_type: str = "rmsnorm"         # "rmsnorm" | "layernorm"
@@ -66,6 +88,8 @@ class ModelConfig:
     dense_d_ff: int = 0                # hidden size of those dense layers
     router_aux_coef: float = 0.001     # load-balance aux loss coefficient
     capacity_factor: float = 1.25      # EP dispatch capacity factor
+    norm_topk_prob: bool = True        # renormalise the top-k gates to sum 1
+    expert_shards: int = 1             # chips sharing each MoE layer (EP)
 
     # --- SSM (Mamba2 / SSD) -----------------------------------------------------
     ssm_state: int = 0                 # N: state dimension per head
@@ -96,8 +120,14 @@ class ModelConfig:
     def __post_init__(self):
         if self.family not in FAMILIES:
             raise ValueError(f"unknown family {self.family!r}")
+        if self.kv_lora_rank:
+            object.__setattr__(self, "head_dim",
+                               self.qk_nope_head_dim + self.qk_rope_head_dim)
         if self.head_dim == 0 and self.n_heads > 0:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+        if self.n_experts % self.expert_shards:
+            raise ValueError(f"{self.n_experts} experts do not split over "
+                             f"{self.expert_shards} chips")
 
     # -- derived quantities ----------------------------------------------------
 
@@ -108,6 +138,19 @@ class ModelConfig:
     @property
     def kv_dim(self) -> int:
         return self.n_kv_heads * self.head_dim
+
+    @property
+    def experts_held(self) -> int:
+        """Routed experts this chip holds: the first ``experts_held`` of the
+        ``n_experts`` the router chooses among."""
+        return self.n_experts // self.expert_shards
+
+    @property
+    def rope_scaling(self) -> Optional[dict]:
+        """The published ``rope_scaling`` entry this config computes."""
+        if self.yarn is None:
+            return None
+        return dict(dataclasses.asdict(self.yarn), type="yarn")
 
     @property
     def d_inner(self) -> int:
@@ -129,6 +172,11 @@ class ModelConfig:
     # -- parameter counting (used for 6·N·D roofline MODEL_FLOPS) ---------------
 
     def _attn_params(self) -> int:
+        if self.kv_lora_rank:                                    # MLA
+            H, r = self.n_heads, self.kv_lora_rank
+            return (self.d_model * (self.q_dim + r + self.qk_rope_head_dim)
+                    + r + r * H * (self.qk_nope_head_dim + self.v_head_dim)
+                    + H * self.v_head_dim * self.d_model)
         p = self.d_model * (self.q_dim + 2 * self.kv_dim)       # QKV
         p += self.q_dim * self.d_model                           # O
         if self.qkv_bias:
@@ -166,7 +214,7 @@ class ModelConfig:
             return self._ssm_params() + self._norm_params()
         p = self._attn_params() + 2 * self._norm_params()
         if self.n_experts and layer_idx >= self.first_k_dense:
-            p += self.n_experts * self._expert_params()
+            p += self.experts_held * self._expert_params()
             p += self.n_shared_experts * self._expert_params()
             p += self.d_model * self.n_experts                   # router
         elif self.n_experts:

@@ -18,6 +18,7 @@ from repro.configs.qwen3_4b import CONFIG as _QWEN3
 from repro.configs.zamba2_12b import CONFIG as _ZAMBA2
 from repro.configs.whisper_tiny import CONFIG as _WHISPER
 from repro.configs.deepseek_moe_16b import CONFIG as _DSMOE
+from repro.configs.deepseek_v2_lite import CONFIG as _DSV2LITE
 from repro.configs.kimi_k2 import CONFIG as _KIMI
 from repro.configs.mamba2_130m import CONFIG as _MAMBA2
 from repro.configs.internvl2_2b import CONFIG as _INTERNVL
@@ -26,7 +27,7 @@ ARCHS: Dict[str, ModelConfig] = {
     c.name: c
     for c in (
         _QWEN15, _STARCODER2, _GRANITE3, _QWEN3, _ZAMBA2,
-        _WHISPER, _DSMOE, _KIMI, _MAMBA2, _INTERNVL,
+        _WHISPER, _DSMOE, _KIMI, _MAMBA2, _INTERNVL, _DSV2LITE,
     )
 }
 
@@ -82,7 +83,11 @@ def smoke_config(arch_id: str) -> ModelConfig:
                   n_shared_experts=min(cfg.n_shared_experts, 1),
                   moe_d_ff=32, d_ff=32, dense_d_ff=96,
                   first_k_dense=min(cfg.first_k_dense, 1),
-                  capacity_factor=8.0)   # effectively dropless at smoke scale
+                  capacity_factor=8.0,   # effectively dropless at smoke scale
+                  expert_shards=min(cfg.expert_shards, 2))
+    if cfg.kv_lora_rank:
+        kw.update(kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+                  v_head_dim=16)
     if cfg.ssm_state:
         kw.update(ssm_state=16, ssm_headdim=16, ssm_chunk=16)
     if cfg.family == "hybrid":

@@ -131,7 +131,7 @@ class ShardingRules:
 
         if name == "embed":
             return tail(self._model(_dim(leaf, 0)), self._fsdp(_dim(leaf, 1)))
-        if name == "unembed":
+        if name == "w_unembed":
             return tail(self._fsdp(_dim(leaf, 0)), self._model(_dim(leaf, 1)))
 
         if "moe" in path and name in ("w_gate", "w_up") and nd >= 3:
@@ -140,7 +140,7 @@ class ShardingRules:
         if "moe" in path and name == "w_down" and nd >= 3:
             return tail(self._model(_dim(leaf, nd - 3)), None,
                         self._fsdp(_dim(leaf, nd - 1)))
-        if name == "router":
+        if name == "w_router":
             return tail(self._fsdp(_dim(leaf, nd - 2)), None)
 
         if ssm_weight:

@@ -8,6 +8,8 @@ Design (TPU-native, not a CUDA port):
     (block, head_dim) tile, which Mosaic needs as (8k or full, 128k or full).
     ``ops.py`` transposes to and from the model's (B, S, H, D).
   * q tile (block_q, head_dim) stays resident; k/v tiles stream through VMEM.
+    v (and the output) may be narrower than q and k (latent attention's
+    192-channel q/k against 128-channel v): each operand has its own spec.
     block sizes default to 128 to align with the 128×128 MXU and 8×128 VREG lanes.
   * causal blocks strictly above the diagonal are skipped via ``pl.when``
     (grid-level work elision, the TPU analogue of warp-level early exit).
@@ -49,7 +51,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     def _compute():
         q = q_ref[...].astype(jnp.float32) * scale          # (bq, D)
         k = k_ref[...].astype(jnp.float32)                  # (bk, D)
-        v = v_ref[...].astype(jnp.float32)                  # (bk, D)
+        v = v_ref[...].astype(jnp.float32)                  # (bk, Dv)
 
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
@@ -89,9 +91,11 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
                            kv_len: int | None = None,
                            block_q: int = 128, block_k: int = 128,
                            interpret: bool = False):
-    """(B, H, Sq, D) x (B, K, Sk, D)^2 -> (B, H, Sq, D).  Sq/Sk padded by ops.py."""
+    """(B, H, Sq, D) x (B, K, Sk, D) x (B, K, Sk, Dv) -> (B, H, Sq, Dv).
+    Sq/Sk padded by ops.py."""
     B, H, Sq, D = q.shape
     _, K, Sk, _ = k.shape
+    Dv = v.shape[-1]
     assert H % K == 0
     group = H // K
     if scale is None:
@@ -107,10 +111,14 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
         block_k=block_k, n_kv_blocks=n_k, q_offset=q_offset, kv_len=kv_len)
 
     grid = (B, H, n_q, n_k)
-    q_spec = pl.BlockSpec((None, None, block_q, D),
-                          lambda b, h, iq, ik: (b, h, iq, 0))
-    kv_spec = pl.BlockSpec((None, None, block_k, D),
-                           lambda b, h, iq, ik: (b, h // group, ik, 0))
+
+    def q_spec(width):
+        return pl.BlockSpec((None, None, block_q, width),
+                            lambda b, h, iq, ik: (b, h, iq, 0))
+
+    def kv_spec(width):
+        return pl.BlockSpec((None, None, block_k, width),
+                            lambda b, h, iq, ik: (b, h // group, ik, 0))
 
     compiler_params = tpu_compiler_params(
         ("parallel", "parallel", "parallel", "arbitrary"))
@@ -118,11 +126,11 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        in_specs=[q_spec(D), kv_spec(D), kv_spec(Dv)],
+        out_specs=q_spec(Dv),
+        out_shape=jax.ShapeDtypeStruct((B, H, Sq, Dv), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),          # acc
+            pltpu.VMEM((block_q, Dv), jnp.float32),         # acc
             pltpu.VMEM((block_q, _MIN_LANES), jnp.float32),  # running max
             pltpu.VMEM((block_q, _MIN_LANES), jnp.float32),  # running denom
         ],

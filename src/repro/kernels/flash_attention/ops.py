@@ -24,7 +24,8 @@ from repro.kernels.flash_attention.kernel import flash_attention_pallas
 def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
                     q_offset: int = 0, backend: str | None = None,
                     block_q: int = 128, block_k: int = 512):
-    """Memory-bounded attention.  Shapes as in ``ref.attention_ref``."""
+    """Memory-bounded attention.  Shapes as in ``ref.attention_ref``; v may
+    have fewer channels than q and k, and the output has v's."""
     b = resolve_backend(backend)
     if b == "xla":
         if scale is None:
@@ -87,6 +88,7 @@ def _flash_xla_fwd_impl(q, k, v, causal, scale, q_offset, block_k):
     # score/accumulator matmuls accumulate in f32 via preferred_element_type.
     B, Sq, H, D = q.shape
     _, Sk, K, _ = k.shape
+    Dv = v.shape[-1]
     G = H // K
     cdt = q.dtype
     Sk_p = round_up(Sk, block_k)
@@ -115,11 +117,11 @@ def _flash_xla_fwd_impl(q, k, v, causal, scale, q_offset, block_k):
 
     m0 = jnp.full((B, K, G, Sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, K, G, Sq), jnp.float32)
-    acc0 = jnp.zeros((B, K, G, Sq, D), jnp.float32)
+    acc0 = jnp.zeros((B, K, G, Sq, Dv), jnp.float32)
     (m, l, acc), _ = jax.lax.scan(body, (m0, l0, acc0), (kb, vb, starts))
     l_safe = jnp.where(l == 0.0, 1.0, l)
-    outg = acc / l_safe[..., None]                            # (B,K,G,Sq,D) f32
-    out = jnp.moveaxis(outg, 3, 1).reshape(B, Sq, H, D).astype(q.dtype)
+    outg = acc / l_safe[..., None]                            # (B,K,G,Sq,Dv) f32
+    out = jnp.moveaxis(outg, 3, 1).reshape(B, Sq, H, Dv).astype(q.dtype)
     return out, m, l_safe
 
 
@@ -132,14 +134,15 @@ def _flash_xla_bwd(causal, scale, q_offset, block_k, res, dout):
     q, k, v, out, m, l = res
     B, Sq, H, D = q.shape
     _, Sk, K, _ = k.shape
+    Dv = v.shape[-1]
     G = H // K
     cdt = q.dtype
     Sk_p = round_up(Sk, block_k)
     kp = pad_axis_to(k, 1, Sk_p).astype(cdt)
     vp = pad_axis_to(v, 1, Sk_p).astype(cdt)
     qg = ((q.astype(jnp.float32) * scale).astype(cdt)).reshape(B, Sq, K, G, D)
-    outg = jnp.moveaxis(out.reshape(B, Sq, K, G, D), 1, 3)
-    dog = jnp.moveaxis(dout.astype(cdt).reshape(B, Sq, K, G, D), 1, 3)
+    outg = jnp.moveaxis(out.reshape(B, Sq, K, G, Dv), 1, 3)
+    dog = jnp.moveaxis(dout.astype(cdt).reshape(B, Sq, K, G, Dv), 1, 3)
     Di = jnp.einsum("bkgqd,bkgqd->bkgq", outg.astype(cdt), dog,
                     preferred_element_type=jnp.float32)       # (B,K,G,Sq)
     q_pos = q_offset + jnp.arange(Sq)
@@ -169,7 +172,7 @@ def _flash_xla_bwd(causal, scale, q_offset, block_k, res, dout):
     dq0 = jnp.zeros((B, Sq, K, G, D), jnp.float32)
     dq, (dk_t, dv_t) = jax.lax.scan(body, dq0, (kb, vb, starts))
     dk = jnp.moveaxis(dk_t, 0, 1).reshape(B, Sk_p, K, D)[:, :Sk]
-    dv = jnp.moveaxis(dv_t, 0, 1).reshape(B, Sk_p, K, D)[:, :Sk]
+    dv = jnp.moveaxis(dv_t, 0, 1).reshape(B, Sk_p, K, Dv)[:, :Sk]
     dq = dq.reshape(B, Sq, H, D)
     return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype))
 

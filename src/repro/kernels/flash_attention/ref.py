@@ -16,13 +16,14 @@ def attention_ref(q, k, v, *, causal: bool = True, scale: float | None = None,
 
     Args:
       q: (B, Sq, H, D)
-      k, v: (B, Sk, K, D) with H % K == 0 (GQA)
+      k: (B, Sk, K, D) with H % K == 0 (GQA)
+      v: (B, Sk, K, Dv); Dv may differ from D (latent attention)
       causal: lower-triangular masking in absolute positions
       scale: logit scale (default 1/sqrt(D))
       q_offset: absolute position of q[0] (decode: cache length)
       kv_len: optional (B,) valid KV lengths (positions >= kv_len are masked)
 
-    Returns: (B, Sq, H, D) in q.dtype.
+    Returns: (B, Sq, H, Dv) in q.dtype.
     """
     B, Sq, H, D = q.shape
     Bk, Sk, K, Dk = k.shape
@@ -49,4 +50,4 @@ def attention_ref(q, k, v, *, causal: bool = True, scale: float | None = None,
     p = jnp.exp(logits - logits.max(axis=-1, keepdims=True))
     p = p / p.sum(axis=-1, keepdims=True)
     out = jnp.einsum("bkgqs,bskd->bqkgd", p, v.astype(jnp.float32))
-    return out.reshape(B, Sq, H, D).astype(q.dtype)
+    return out.reshape(B, Sq, H, v.shape[-1]).astype(q.dtype)

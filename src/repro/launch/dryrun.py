@@ -111,13 +111,6 @@ def exec_for(cfg: ModelConfig, shape: ShapeConfig,
             kw["moe_group_size"] = 256
     else:
         kw["loss_chunk"] = 0
-        kw["moe_group_size"] = 128
-        if shape.kind == "decode" and cfg.n_experts:
-            # single-group capacity dispatch: honest FLOPs accounting (the
-            # sorted/ragged path lowers dense on CPU), <0.1% drops at cf=4
-            kw["moe_decode_impl"] = "einsum"
-            kw["moe_capacity_override"] = 4.0
-            kw["moe_group_size"] = 8192
     if overrides:
         kw.update(overrides)
     return ExecConfig(**kw)

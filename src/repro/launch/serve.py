@@ -43,14 +43,26 @@ def make_infer_function(model, treedef, host_leaves, prompt_len: int = 16,
     ``kernels/state_push`` path; ``"auto"`` = the per-key adaptive
     ``WirePolicy``) — the stateful-serving traffic the wire choice is
     about.  The warm-replica refresh before each push rides the wire fabric
-    too: only the retained delta is pulled."""
+    too: only the retained delta is pulled.
+
+    A model whose parameters hold a router (``w_router``) is served through
+    ``model.routed_logits``: the token slots routed to each held expert in
+    each MoE layer come to the host with the token, ride on the call's
+    ``serve.forward`` span (``moe_rows``, ``moe_rows_max``) and add to the
+    runtime's ``faasm_serve_moe_routed_rows_total`` and
+    ``faasm_serve_moe_busiest_rows_total`` counters."""
     from repro.core import FunctionDef
 
+    routed = any(getattr(path[-1], "key", None) == "w_router"
+                 for path, _ in jax.tree_util.tree_flatten_with_path(
+                     jax.tree_util.tree_unflatten(treedef, host_leaves))[0])
+    forward = model.routed_logits if routed else model.logits
+
     def _build_fwd():
-        fwd = jax.jit(lambda p, t: model.logits(p, t))
+        fwd = jax.jit(lambda p, t: forward(p, t))
         p = jax.tree_util.tree_unflatten(
             treedef, [jnp.asarray(x) for x in host_leaves])
-        fwd(p, jnp.zeros((1, prompt_len), jnp.int32)).block_until_ready()
+        jax.block_until_ready(fwd(p, jnp.zeros((1, prompt_len), jnp.int32)))
         return fwd
 
     def init(api):
@@ -76,10 +88,25 @@ def make_infer_function(model, treedef, host_leaves, prompt_len: int = 16,
         if span is not None:
             tel.end(span)
             span = tel.begin("serve.forward", "serve")
-        logits = fwd(p, jnp.asarray(tokens))
-        tok = int(np.asarray(jnp.argmax(logits[0, -1])))
-        if span is not None:
-            tel.end(span)
+        if routed:
+            logits, rows = fwd(p, jnp.asarray(tokens))
+            tok, rows = jax.device_get((jnp.argmax(logits[0, -1]), rows))
+            tok = int(tok)
+            busiest = int(rows.max())
+            if span is not None:
+                tel.end(span, moe_rows=rows.tolist(), moe_rows_max=busiest)
+            reg = api.runtime.metrics
+            reg.counter("faasm_serve_moe_routed_rows_total",
+                        "token slots routed to held experts").inc(
+                            int(rows.sum()))
+            reg.counter("faasm_serve_moe_busiest_rows_total",
+                        "per call, the slots of its busiest held expert in "
+                        "any MoE layer").inc(busiest)
+        else:
+            logits = fwd(p, jnp.asarray(tokens))
+            tok = int(np.asarray(jnp.argmax(logits[0, -1])))
+            if span is not None:
+                tel.end(span)
         if state_wire is not None:
             from repro.state.ddo import VectorAsync
             stats = VectorAsync(api, "serve/stats")

@@ -15,9 +15,9 @@ class ExecConfig:
     backend: str = "auto"            # kernel dispatch: auto|xla|pallas|pallas_interpret
     remat: str = "full"              # "none" | "full" | "dots"
     scan_layers: bool = True         # lax.scan over stacked layer params
-    moe_impl: str = "einsum"         # "einsum" (GShard dense dispatch) | "sorted" (gmm)
-    moe_decode_impl: str = "sorted"  # decode steps: "sorted" (exact) | "einsum"
-    moe_capacity_override: float = 0.0   # >0 overrides cfg.capacity_factor
+    moe_impl: str = "einsum"         # training: "einsum" (GShard dense
+                                     # dispatch) | "sorted" (dropless gmm);
+                                     # passes that do not train are dropless
     moe_group_size: int = 1024       # GShard dispatch group size (tokens)
     loss_chunk: int = 512            # seq chunk for fused unembed+xent (0 = off)
     attn_block_k: int = 512          # xla flash attention KV tile

@@ -2,12 +2,13 @@
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import ModelConfig, YarnScaling
 
 
 def dt(cfg_dtype: str):
@@ -70,6 +71,54 @@ def rope_apply(x, positions, theta: float):
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# YaRN rope on interleaved channel pairs (DeepSeek-V2)
+# ---------------------------------------------------------------------------
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, yarn: Optional[YarnScaling]):
+    """(dim // 2,) inverse frequencies: plain rope's, or with ``yarn`` those
+    of DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding`` — each interpolated
+    by ``factor`` below the band that ``beta_fast``..``beta_slow`` turns
+    within the original window mark as high-frequency, blended linearly
+    across it."""
+    extra = 1.0 / theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    if yarn is None:
+        return extra
+
+    def corr(rot):
+        return (dim * math.log(yarn.original_max_position_embeddings
+                               / (rot * 2 * math.pi))) / (2 * math.log(theta))
+
+    low = max(math.floor(corr(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr(yarn.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return extra / yarn.factor * ramp + extra * (1.0 - ramp)
+
+
+def rope_apply_interleaved(x, positions, inv_freq):
+    """DeepSeek-V2's rope: x (B, S, H, D) holds its pairs interleaved
+    (x0, x1), (x2, x3), ...; they are regrouped as [x0, x2, ..., x1, x3,
+    ...] and rotated in the rotate-half convention, so the result is in
+    that regrouped order.  positions: (S,) or (B, S)."""
+    B, S, H, D = x.shape
+    x = x.astype(jnp.float32).reshape(B, S, H, D // 2, 2).swapaxes(-1, -2)
+    x1, x2 = x[..., 0, :], x[..., 1, :]
+    pos = positions.astype(jnp.float32)
+    if pos.ndim == 1:
+        pos = pos[None, :]
+    ang = pos[..., None] * inv_freq                           # (B?, S, D/2)
+    cos = jnp.cos(ang)[:, :, None, :]
+    sin = jnp.sin(ang)[:, :, None, :]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
 def seq_shard_constraint(h, wide: bool = False):
@@ -161,7 +210,7 @@ def embed_init(key, cfg: ModelConfig):
     pdt = dt(cfg.param_dtype)
     p = {"embed": trunc_normal(key, (cfg.vocab_size, cfg.d_model), 0.02, pdt)}
     if not cfg.tie_embeddings:
-        p["unembed"] = trunc_normal(jax.random.fold_in(key, 1),
+        p["w_unembed"] = trunc_normal(jax.random.fold_in(key, 1),
                                     (cfg.d_model, cfg.vocab_size),
                                     cfg.d_model ** -0.5, pdt)
     return p
@@ -172,7 +221,7 @@ def embed_apply(p, cfg: ModelConfig, tokens):
 
 
 def unembed_matrix(p, cfg: ModelConfig):
-    return p["embed"].T if cfg.tie_embeddings else p["unembed"]
+    return p["embed"].T if cfg.tie_embeddings else p["w_unembed"]
 
 
 def logits_apply(p, cfg: ModelConfig, h, f32: bool = True):

@@ -60,6 +60,12 @@ class Model:
                                             extra)
         return self._mod.forward_logits(params, self.cfg, self.ec, tokens)
 
+    def routed_logits(self, params, tokens):
+        """(logits, rows): ``rows`` (n_moe_layers, experts_held) int32, the
+        token slots routed to each held expert in each MoE layer."""
+        return self._mod.forward_logits_routed(params, self.cfg, self.ec,
+                                               tokens)
+
     # -- serving -----------------------------------------------------------------
     def init_cache(self, batch: int, max_len: int):
         return self._mod.init_cache(self.cfg, batch, max_len)

@@ -37,20 +37,21 @@ def moe_block_init(key, cfg: ModelConfig):
             "ln2": L.norm_init(cfg), "moe": moe_init(ks[1], cfg)}
 
 
-def _ffn(lp, cfg, ec, h):
-    """Second half of a block: returns (delta, aux)."""
+def _ffn(lp, cfg, ec, h, train):
+    """Second half of a block: returns (delta, aux, rows), ``rows`` the
+    token slots routed to each held expert (None for a dense MLP)."""
     x = L.norm_apply(lp["ln2"], cfg, h)
     if "moe" in lp:
-        y, aux = moe_apply(lp["moe"], cfg, ec, x)
-        return y, aux
-    return L.mlp_apply(lp["mlp"], cfg, x), jnp.zeros((), jnp.float32)
+        return moe_apply(lp["moe"], cfg, ec, x, train=train)
+    return L.mlp_apply(lp["mlp"], cfg, x), jnp.zeros((), jnp.float32), None
 
 
-def block_full(lp, cfg: ModelConfig, ec: ExecConfig, h, positions=None):
+def block_full(lp, cfg: ModelConfig, ec: ExecConfig, h, positions=None,
+               train: bool = True):
     h = h + attn_apply_full(lp["attn"], cfg, ec,
                             L.norm_apply(lp["ln1"], cfg, h), positions=positions)
-    delta, aux = _ffn(lp, cfg, ec, h)
-    return h + delta, aux
+    delta, aux, rows = _ffn(lp, cfg, ec, h, train)
+    return h + delta, aux, rows
 
 
 def block_prefill(lp, cfg, ec, h, ck, cv, positions=None):
@@ -58,7 +59,7 @@ def block_prefill(lp, cfg, ec, h, ck, cv, positions=None):
                                    L.norm_apply(lp["ln1"], cfg, h), ck, cv,
                                    positions=positions)
     h = h + a
-    delta, _ = _ffn(lp, cfg, ec, h)
+    delta, _, _ = _ffn(lp, cfg, ec, h, train=False)
     return h + delta, ck, cv
 
 
@@ -66,7 +67,7 @@ def block_decode(lp, cfg, ec, h, ck, cv, index):
     a, ck, cv = attn_apply_decode(lp["attn"], cfg, ec,
                                   L.norm_apply(lp["ln1"], cfg, h), ck, cv, index)
     h = h + a
-    delta, _ = _ffn(lp, cfg, ec, h)
+    delta, _, _ = _ffn(lp, cfg, ec, h, train=False)
     return h + delta, ck, cv
 
 
@@ -116,38 +117,43 @@ def _embed_inputs(params, cfg: ModelConfig, tokens, image_embeds=None):
 
 def forward_hidden(params, cfg: ModelConfig, ec: ExecConfig, tokens,
                    image_embeds=None, train: bool = True):
-    """Returns (h (B, S_total, d) post-final-norm, aux_loss)."""
+    """Returns (h (B, S_total, d) post-final-norm, aux_loss, rows): rows
+    (n_moe_layers, experts_held) int32, the token slots routed to each held
+    expert in each scanned MoE layer, or None without routed experts."""
     h = _embed_inputs(params, cfg, tokens, image_embeds)
     S = h.shape[1]
     positions = jnp.arange(S) if cfg.use_rope else None
     aux = jnp.zeros((), jnp.float32)
     for lp in params.get("first_layers", []):
-        h2, a = block_full(lp, cfg, ec, h, positions)
+        h2, a, _ = block_full(lp, cfg, ec, h, positions, train)
         h, aux = h2, aux + a
 
     def body(carry, lp):
         h, aux = carry
         if train and ec.shard_activations:
             h = L.seq_shard_constraint(h)
-        h2, a = block_full(lp, cfg, ec, h, positions)
-        return (h2, aux + a), None
+        h2, a, rows = block_full(lp, cfg, ec, h, positions, train)
+        return (h2, aux + a), rows
 
     if train:
         body = _maybe_remat(body, ec)
     if ec.scan_layers:
-        (h, aux), _ = jax.lax.scan(body, (h, aux), params["layers"])
+        (h, aux), rows = jax.lax.scan(body, (h, aux), params["layers"])
     else:
         n = jax.tree_util.tree_leaves(params["layers"])[0].shape[0]
+        per_layer = []
         for i in range(n):
             lp = jax.tree.map(lambda x: x[i], params["layers"])
-            (h, aux), _ = body((h, aux), lp)
-    return L.norm_apply(params["final_norm"], cfg, h), aux
+            (h, aux), r = body((h, aux), lp)
+            per_layer.append(r)
+        rows = None if per_layer[0] is None else jnp.stack(per_layer)
+    return L.norm_apply(params["final_norm"], cfg, h), aux, rows
 
 
 def forward_train(params, cfg: ModelConfig, ec: ExecConfig, batch):
     """batch: tokens/targets/mask (+image_embeds).  Returns (loss, metrics)."""
-    h, aux = forward_hidden(params, cfg, ec, batch["tokens"],
-                            batch.get("image_embeds"), train=True)
+    h, aux, _ = forward_hidden(params, cfg, ec, batch["tokens"],
+                               batch.get("image_embeds"), train=True)
     if cfg.family == "vlm":
         h = h[:, cfg.n_image_tokens:]            # loss only over text positions
     loss = L.chunked_loss(params, cfg, h, batch["targets"], batch["mask"],
@@ -156,10 +162,18 @@ def forward_train(params, cfg: ModelConfig, ec: ExecConfig, batch):
     return total, {"loss": loss, "aux_loss": aux}
 
 
+def forward_logits_routed(params, cfg: ModelConfig, ec: ExecConfig, tokens,
+                          image_embeds=None):
+    """(logits, rows): the logits and, beside them, the token slots routed
+    to each held expert in each MoE layer (``forward_hidden``)."""
+    h, _, rows = forward_hidden(params, cfg, ec, tokens, image_embeds,
+                                train=False)
+    return L.logits_apply(params, cfg, h, f32=ec.logits_f32), rows
+
+
 def forward_logits(params, cfg: ModelConfig, ec: ExecConfig, tokens,
                    image_embeds=None):
-    h, _ = forward_hidden(params, cfg, ec, tokens, image_embeds, train=False)
-    return L.logits_apply(params, cfg, h, f32=ec.logits_f32)
+    return forward_logits_routed(params, cfg, ec, tokens, image_embeds)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -167,14 +181,20 @@ def forward_logits(params, cfg: ModelConfig, ec: ExecConfig, tokens,
 # ---------------------------------------------------------------------------
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int):
+    """Per layer ``k`` and ``v`` (B, max_len, K, D); under latent attention
+    ``k`` holds the latent (B, max_len, kv_lora_rank) and ``v`` the shared
+    rope key (B, max_len, qk_rope_head_dim)."""
     n_first = cfg.first_k_dense if cfg.n_experts else 0
     n_scan = cfg.n_layers - n_first
-    kv = lambda n: jnp.zeros((n, batch, max_len, cfg.n_kv_heads, cfg.head_dim),
-                             L.dt(cfg.dtype))
-    cache = {"k": kv(n_scan), "v": kv(n_scan)}
+    if cfg.kv_lora_rank:
+        widths = (cfg.kv_lora_rank,), (cfg.qk_rope_head_dim,)
+    else:
+        widths = ((cfg.n_kv_heads, cfg.head_dim),) * 2
+    kv = lambda n, w: jnp.zeros((n, batch, max_len) + w, L.dt(cfg.dtype))
+    cache = {"k": kv(n_scan, widths[0]), "v": kv(n_scan, widths[1])}
     if n_first:
-        cache["first_k"] = kv(n_first)
-        cache["first_v"] = kv(n_first)
+        cache["first_k"] = kv(n_first, widths[0])
+        cache["first_v"] = kv(n_first, widths[1])
     return cache
 
 

@@ -23,21 +23,24 @@ def _randn(*shape, dtype=jnp.float32):
 # ---------------------------------------------------------------------------
 
 FLASH_CASES = [
-    # B, Sq, Sk, H, K, D, causal, q_offset
-    (2, 16, 16, 4, 2, 16, True, 0),
-    (1, 8, 24, 4, 4, 8, True, 16),
-    (2, 17, 33, 6, 2, 16, False, 0),
-    (1, 1, 40, 8, 2, 32, True, 39),
-    (2, 16, 16, 4, 1, 16, True, 0),          # MQA
+    # B, Sq, Sk, H, K, D, causal, q_offset, Dv (v's channels)
+    (2, 16, 16, 4, 2, 16, True, 0, 16),
+    (1, 8, 24, 4, 4, 8, True, 16, 8),
+    (2, 17, 33, 6, 2, 16, False, 0, 16),
+    (1, 1, 40, 8, 2, 32, True, 39, 32),
+    (2, 16, 16, 4, 1, 16, True, 0, 16),      # MQA
+    (1, 20, 20, 4, 4, 24, True, 0, 16),      # latent attention: Dv < D, padded
+    (2, 13, 29, 4, 4, 24, True, 16, 8),
 ]
 
 
 @pytest.mark.parametrize("case", FLASH_CASES)
 @pytest.mark.parametrize("backend", ["xla", "pallas_interpret"])
 def test_flash_attention_matches_ref(case, backend):
-    B, Sq, Sk, H, K, D, causal, off = case
-    q, k, v = _randn(B, Sq, H, D), _randn(B, Sk, K, D), _randn(B, Sk, K, D)
+    B, Sq, Sk, H, K, D, causal, off, Dv = case
+    q, k, v = _randn(B, Sq, H, D), _randn(B, Sk, K, D), _randn(B, Sk, K, Dv)
     ref = attention_ref(q, k, v, causal=causal, q_offset=off)
+    assert ref.shape == (B, Sq, H, Dv)
     got = flash_attention(q, k, v, causal=causal, q_offset=off,
                           backend=backend, block_q=8, block_k=8)
     np.testing.assert_allclose(ref, got, atol=2e-5, rtol=2e-5)
@@ -59,6 +62,19 @@ def test_flash_attention_dtypes(dtype):
 
 def test_flash_attention_grads_match_ref_autodiff():
     q, k, v = _randn(2, 16, 4, 16), _randn(2, 16, 2, 16), _randn(2, 16, 2, 16)
+    f_ref = lambda q, k, v: (attention_ref(q, k, v) ** 2).sum()
+    f_fa = lambda q, k, v: (flash_attention(q, k, v, backend="xla",
+                                            block_k=8) ** 2).sum()
+    g_ref = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
+    g_fa = jax.grad(f_fa, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g_ref, g_fa):
+        np.testing.assert_allclose(a, b, atol=3e-5, rtol=3e-5)
+
+
+def test_flash_attention_grads_with_narrower_values():
+    """The xla path's flash backward with v narrower than q and k (latent
+    attention trains through it)."""
+    q, k, v = _randn(1, 16, 4, 24), _randn(1, 16, 4, 24), _randn(1, 16, 4, 16)
     f_ref = lambda q, k, v: (attention_ref(q, k, v) ** 2).sum()
     f_fa = lambda q, k, v: (flash_attention(q, k, v, backend="xla",
                                             block_k=8) ** 2).sum()

@@ -37,8 +37,8 @@ def test_einsum_vs_sorted_dispatch_equivalent():
     cfg, p, x = _setup(capacity_factor=8.0)
     ec_e = ExecConfig(backend="xla", moe_impl="einsum", moe_group_size=32)
     ec_s = ExecConfig(backend="xla", moe_impl="sorted")
-    y_e, aux_e = moe_apply(p, cfg, ec_e, x)
-    y_s, aux_s = moe_apply(p, cfg, ec_s, x)
+    y_e, aux_e, _ = moe_apply(p, cfg, ec_e, x)
+    y_s, aux_s, _ = moe_apply(p, cfg, ec_s, x)
     np.testing.assert_allclose(np.asarray(y_e), np.asarray(y_s),
                                atol=2e-4, rtol=2e-4)
     np.testing.assert_allclose(float(aux_e), float(aux_s), rtol=1e-5)
@@ -49,9 +49,9 @@ def test_einsum_low_capacity_drops_tokens():
     never corrupted."""
     cfg, p, x = _setup(capacity_factor=8.0)
     ec_lo = ExecConfig(backend="xla", moe_impl="einsum", moe_group_size=32)
-    y_hi, _ = moe_apply(p, cfg, ec_lo, x)
+    y_hi, _, _ = moe_apply(p, cfg, ec_lo, x)
     cfg_lo = cfg.with_overrides(capacity_factor=0.25)
-    y_lo, _ = moe_apply(p, cfg_lo, ec_lo, x)
+    y_lo, _, _ = moe_apply(p, cfg_lo, ec_lo, x)
     # dropped tokens shrink toward the shared-expert-only output
     assert float(jnp.abs(y_lo).mean()) <= float(jnp.abs(y_hi).mean()) + 1e-6
 
@@ -61,13 +61,13 @@ def test_moe_grads_flow_to_all_parts():
     ec = ExecConfig(backend="xla", moe_impl="einsum", moe_group_size=32)
 
     def loss(p):
-        y, aux = moe_apply(p, cfg, ec, x)
+        y, aux, _ = moe_apply(p, cfg, ec, x)
         return (y ** 2).mean() + aux
 
     g = jax.grad(loss)(p)
     for path, leaf in jax.tree_util.tree_flatten_with_path(g)[0]:
         name = jax.tree_util.keystr(path)
         assert bool(jnp.isfinite(leaf).all()), name
-    assert float(jnp.abs(g["router"]).sum()) > 0
+    assert float(jnp.abs(g["w_router"]).sum()) > 0
     assert float(jnp.abs(g["w_gate"]).sum()) > 0
     assert float(jnp.abs(g["shared"]["w_up"]).sum()) > 0

@@ -9,7 +9,10 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 this file.
 """
+import base64
+import hashlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -62,6 +65,16 @@ def _flash(arch, S):
             [((1, S, H, D), BF16), ((1, S, K, D), BF16), ((1, S, K, D), BF16)])
 
 
+def _mla_flash(arch, S):
+    """Latent attention's prefill: q and k of nope + rope channels, v of
+    v_head_dim."""
+    cfg = get_config(arch)
+    H, D, Dv = cfg.n_heads, cfg.head_dim, cfg.v_head_dim
+    return (lambda q, k, v: flash_attention(q, k, v, backend="pallas",
+                                            scale=D ** -0.5),
+            [((1, S, H, D), BF16), ((1, S, H, D), BF16), ((1, S, H, Dv), BF16)])
+
+
 def _decode(arch, B, S):
     H, K, D = _attn(arch)
     return (lambda q, k, v, n: decode_attention(q, k, v, n, backend="pallas"),
@@ -87,6 +100,16 @@ def _gmm(arch, rows):
             [((rows, d), BF16), ((E, d, f), BF16), ((E,), I32)])
 
 
+def _gmm_held(arch, S):
+    """The served forward's grouped matmul: every routed slot of an
+    S-token prompt, over the experts this chip holds."""
+    cfg = get_config(arch)
+    E, d, f = cfg.experts_held, cfg.d_model, cfg.moe_d_ff
+    return (lambda x, w, sizes: gmm(x, w, sizes, backend="pallas"),
+            [((S * cfg.experts_per_token, d), BF16), ((E, d, f), BF16),
+             ((E,), I32)])
+
+
 def _quantize(n):
     return (lambda a, b: state_push.quantize_delta(a, b, backend="pallas"),
             [((n,), F32), ((n,), F32)])
@@ -109,6 +132,9 @@ def _apply(n):
 CASES = {
     "flash-qwen1.5-0.5b-S512": lambda: _flash("qwen1.5-0.5b", 512),
     "flash-qwen3-4b-S1024": lambda: _flash("qwen3-4b", 1024),
+    "flash-mla-deepseek-v2-lite-S2048": lambda: _mla_flash(
+        "deepseek-v2-lite", 2048),
+    "gmm-deepseek-v2-lite-S2048": lambda: _gmm_held("deepseek-v2-lite", 2048),
     "decode-qwen1.5-0.5b-B8-S2048": lambda: _decode("qwen1.5-0.5b", 8, 2048),
     "decode-qwen3-4b-B8-S2048": lambda: _decode("qwen3-4b", 8, 2048),
     "ssd-mamba2-130m-S512": lambda: _ssd("mamba2-130m", 512),
@@ -127,3 +153,37 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
     hlo = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in hlo, case
+
+
+def _without_locations(hlo: str) -> str:
+    """``hlo`` with each Mosaic kernel's serialized module replaced by its
+    text without source locations, which name files and lines of the
+    checkout and so differ between two copies of one program."""
+    from jax._src.lib.mlir import ir
+
+    def text(m):
+        with ir.Context() as ctx:
+            ctx.allow_unregistered_dialects = True
+            mod = ir.Module.parse(base64.b64decode(m.group(1)))
+            return mod.operation.get_asm(enable_debug_info=False)
+
+    return re.sub(r"\\22body\\22: \\22([A-Za-z0-9+/=]+)", text, hlo)
+
+
+# sha256 of ``_without_locations`` of the lowered flash_attention call at
+# qwen1.5-0.5b's served shape (1, 384, 16 heads, 64 channels), taken at the
+# commit before v got a width of its own (JAX 0.9.0)
+QWEN_FLASH_HLO_SHA256 = (
+    "70d0f605177f83302b1c31a4cd14a138024eb3d7f4b8377e66325d394f1900cf")
+
+
+def test_equal_width_flash_attention_lowers_as_before(one_chip):
+    """With q, k and v of one width the kernel and the HLO around it are
+    the ones qwen1.5-0.5b's cell measured before latent attention."""
+    args = [jax.ShapeDtypeStruct((1, 384, 16, 64), BF16, sharding=one_chip)
+            ] * 3
+    hlo = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, backend="pallas")).lower(*args).as_text()
+    assert "tpu_custom_call" in hlo
+    got = hashlib.sha256(_without_locations(hlo).encode()).hexdigest()
+    assert got == QWEN_FLASH_HLO_SHA256
