@@ -3,7 +3,7 @@ from repro.core.faaslet import (CONTAINER_OVERHEAD_BYTES,
                                 FAASLET_OVERHEAD_BYTES, ArenaBase, Faaslet,
                                 FaasletMemoryFault, ResourceLimitExceeded)
 from repro.core.host_interface import CallCancelled, FaasmAPI, StateKeyError
-from repro.core.proto import ExecutableCache, ProtoFaaslet
+from repro.core.proto import DeviceRegion, ExecutableCache, ProtoFaaslet
 from repro.core.runtime import (BatchTimeout, Call, CompletionLatch,
                                 FaasmRuntime, FunctionDef, Host)
 from repro.core.scheduler import LocalScheduler
@@ -13,7 +13,8 @@ from repro.core.vfs import VirtualFS
 __all__ = [
     "ArenaBase", "Faaslet", "FaasletMemoryFault", "ResourceLimitExceeded",
     "FaasmAPI", "CallCancelled",
-    "StateKeyError", "ExecutableCache", "ProtoFaaslet", "Call",
+    "StateKeyError", "DeviceRegion", "ExecutableCache", "ProtoFaaslet",
+    "Call",
     "BatchTimeout", "CompletionLatch", "FaasmRuntime",
     "FunctionDef", "Host", "LocalScheduler", "await_all", "chain", "outputs",
     "VirtualFS", "FAASLET_OVERHEAD_BYTES", "CONTAINER_OVERHEAD_BYTES",

@@ -24,6 +24,13 @@ init-code products are decoded once into a cached template instead of paying
 restores on the process — the same discipline as the shared state tier
 (§3.3); functions must not mutate it.  The pre-CoW full-copy path survives
 as :meth:`ProtoFaaslet.restore_copy` (the benchmark baseline).
+
+Init products that a function reads on the accelerator (a model's weights)
+travel as a :class:`DeviceRegion`: the snapshot carries their numpy leaves,
+and they are placed on the device once per snapshot per process, on the
+first bind.  Every Faaslet restored from the snapshot binds the same
+read-only device arrays instead of copying the leaves over the host-device
+link on each call; they must never be donated to a jitted function.
 """
 from __future__ import annotations
 
@@ -39,6 +46,58 @@ from repro.telemetry import clock as tclock
 
 _cache_lock = threading.Lock()
 _PICKLE_FIELDS = ("func_name", "arena", "brk", "memory_limit", "user_state")
+
+
+class DeviceRegion:
+    """A shared, read-only device region for a snapshot's arrays.
+
+    Holds a treedef and the numpy leaves; :meth:`bind` returns the tree of
+    device arrays.  The first bind places every leaf in one
+    ``jax.device_put`` (double-checked under a lock, so Faaslets that
+    cold-start together place once); later binds get the same arrays back.
+    The arrays are shared by every call that binds them: read-only, and
+    never donated to a jitted function, which would invalidate them for
+    every other holder.  Pickling drops the device arrays, so snapshot bytes
+    stay portable and a region restored elsewhere places its own copy on its
+    first bind."""
+
+    def __init__(self, treedef, leaves):
+        self.treedef = treedef
+        self.leaves = list(leaves)
+        self.nbytes = sum(x.nbytes for x in self.leaves)
+        self._tree = None
+        self._lock = threading.Lock()
+
+    def bind(self, metrics) -> Tuple[Any, bool]:
+        """The device tree, and whether this bind placed it.  Counts the
+        bind (``faasm_proto_region_binds_total``) and a placement with its
+        bytes (``faasm_proto_region_placements_total``,
+        ``faasm_proto_region_placed_bytes_total``) in the registry
+        ``metrics``."""
+        tree, placed = self._tree, False
+        if tree is None:
+            with self._lock:
+                tree = self._tree
+                if tree is None:
+                    import jax
+                    tree = jax.tree_util.tree_unflatten(
+                        self.treedef, jax.device_put(self.leaves))
+                    self._tree, placed = tree, True
+        metrics.counter("faasm_proto_region_binds_total",
+                        "device region binds (one per call)").inc()
+        if placed:
+            metrics.counter("faasm_proto_region_placements_total",
+                            "device regions placed on the device").inc()
+            metrics.counter("faasm_proto_region_placed_bytes_total",
+                            "bytes placed on the device by region "
+                            "placements").inc(self.nbytes)
+        return tree, placed
+
+    def __getstate__(self):
+        return {"treedef": self.treedef, "leaves": self.leaves}
+
+    def __setstate__(self, state):
+        self.__init__(state["treedef"], state["leaves"])
 
 
 @dataclass(frozen=True)

@@ -742,8 +742,11 @@ class FaasmRuntime:
             data = (self.global_tier.get(key, host=host) if transfer
                     else self.global_tier.get(key, host="cache"))
             p = ProtoFaaslet.deserialize(data)
+            # cold starts racing here each decode the snapshot; the first
+            # stored wins, so every restore shares one proto (one decoded
+            # template, one device region)
             with self._mutex:
-                self._protos[fn] = p
+                p = self._protos.setdefault(fn, p)
         return p
 
     # -- modules (dlopen) --------------------------------------------------------

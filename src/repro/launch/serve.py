@@ -33,8 +33,14 @@ def make_infer_function(model, treedef, host_leaves, prompt_len: int = 16,
     """Build the FAASM ``infer`` FunctionDef for a single-shot forward pass.
 
     The jitted executable lands in the runtime's ExecutableCache under
-    ``cache_key``; the (numpy, picklable) weights travel in the Proto-Faaslet
-    snapshot.  Shared by :func:`run_faasm_fanout` and
+    ``cache_key``.  The weights are the init's
+    :class:`~repro.core.proto.DeviceRegion`: its numpy leaves travel in the
+    Proto-Faaslet snapshot, and they are placed on the device once per
+    snapshot per process, on the first call's bind.  Every call restored
+    from that snapshot binds the same read-only device arrays (a container
+    re-runs the init, so it places its own).  The jitted forward donates
+    nothing: donating the shared arrays would invalidate them for every
+    other call.  Shared by :func:`run_faasm_fanout` and
     ``examples/inference_serving.py``.
 
     With ``state_wire`` set, each request additionally accumulates the
@@ -51,7 +57,7 @@ def make_infer_function(model, treedef, host_leaves, prompt_len: int = 16,
     ``serve.forward`` span (``moe_rows``, ``moe_rows_max``) and add to the
     runtime's ``faasm_serve_moe_routed_rows_total`` and
     ``faasm_serve_moe_busiest_rows_total`` counters."""
-    from repro.core import FunctionDef
+    from repro.core import DeviceRegion, FunctionDef
 
     routed = any(getattr(path[-1], "key", None) == "w_router"
                  for path, _ in jax.tree_util.tree_flatten_with_path(
@@ -67,26 +73,24 @@ def make_infer_function(model, treedef, host_leaves, prompt_len: int = 16,
 
     def init(api):
         api.runtime.exec_cache.get_or_build(cache_key, _build_fwd)
-        return {"params": host_leaves}
-
-    nbytes, leaves = sum(x.nbytes for x in host_leaves), len(host_leaves)
+        return {"params": DeviceRegion(treedef, host_leaves)}
 
     def infer(api):
         tel = _TEL
-        state = api.host.user_state(api.faaslet)
+        region = api.host.user_state(api.faaslet)["params"]
         fwd, _, _ = api.runtime.exec_cache.get_or_build(cache_key, _build_fwd)
         tokens = np.frombuffer(api.read_call_input(),
                                np.int32).reshape(1, -1)
-        # serve.weights: the snapshot's leaves enqueued onto the device
-        span = (tel.begin("serve.weights", "serve", nbytes=nbytes,
-                          leaves=leaves)
+        # serve.weights: the bind of the snapshot's device region; the
+        # first bind in the process places the leaves (placed=True)
+        span = (tel.begin("serve.weights", "serve", nbytes=region.nbytes,
+                          leaves=len(region.leaves))
                 if tel is not None else None)
-        p = jax.tree_util.tree_unflatten(
-            treedef, [jnp.asarray(x) for x in state["params"]])
+        p, placed = region.bind(api.runtime.metrics)
         # serve.forward: dispatch until the token is on the host, so it
-        # holds the wait for the weights' copies and the forward itself
+        # holds the forward and any wait for a placement still in flight
         if span is not None:
-            tel.end(span)
+            tel.end(span, placed=placed)
             span = tel.begin("serve.forward", "serve")
         if routed:
             logits, rows = fwd(p, jnp.asarray(tokens))

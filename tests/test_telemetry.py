@@ -330,7 +330,7 @@ def _within(inner, outer):
 
 
 def test_infer_body_spans_nest_under_their_call(tiny_lm):
-    """An armed ``infer`` call records the weights' enqueue, the forward,
+    """An armed ``infer`` call records the weights' bind, the forward,
     the int8 encode and the replica-lock waits, each on the call's thread
     with its id, inside ``call.exec`` (the encode inside ``wire.push``)."""
     t = telemetry.enable()
@@ -340,6 +340,7 @@ def test_infer_body_spans_nest_under_their_call(tiny_lm):
     finally:
         telemetry.disable()
     _, _, leaves, _ = tiny_lm
+    placed = []
     for cid in cids:
         mine = [s for s in got if s.call == cid]
         one = {name: _spans_named(mine, name) for name in (
@@ -354,7 +355,9 @@ def test_infer_body_spans_nest_under_their_call(tiny_lm):
         assert _within(one["wire.encode"], one["wire.push"])
         assert one["serve.weights"].t1 <= one["serve.forward"].t0
         assert one["serve.forward"].t1 <= one["wire.push"].t0
-        assert one["serve.weights"].tags == {
+        tags = dict(one["serve.weights"].tags)
+        placed.append(tags.pop("placed"))
+        assert tags == {
             "nbytes": sum(x.nbytes for x in leaves), "leaves": len(leaves)}
         assert one["wire.encode"].tags == {"key": "serve/stats",
                                            "wire": "int8"}
@@ -362,6 +365,8 @@ def test_infer_body_spans_nest_under_their_call(tiny_lm):
         locks = _spans_named(mine, "state.lock")
         assert {s.tags["site"] for s in locks} == {"pull", "base"}
         assert all(_within(s, ex) for s in locks)
+    # one snapshot, one placement: the first bind places, the other binds
+    assert sorted(placed) == [False, True]
 
 
 def test_compile_inside_a_call_is_a_jax_compile_span():
