@@ -264,120 +264,6 @@ def _bench_pull_wire() -> dict:
     return rows
 
 
-def _bench_codec_trace() -> dict:
-    """``--trace``: arm the telemetry plane and derive the per-wire
-    encode-cost curve per value size from the flight recorder — every row
-    comes from ``wire.push`` span tags (``encode_ns``, ``nbytes``, span
-    wall), not from ad-hoc timers around the push loop.
-
-    Fixed-wire rows (exact/int8/int4/fp8) run with the :class:`WireCostModel`
-    armed, so by the time the ``auto`` row runs the model has one bucket of
-    evidence per wire at that size and ``WirePolicy`` argmin-picks instead of
-    probing.  Each size also gets a ``crossover_mbps`` summary per quantised
-    tier: the link bandwidth below which that tier's byte savings outrun its
-    extra encode cost (``inf`` when it already wins on this host's
-    in-process fabric).  Written to ``BENCH_codec.json`` — the same file
-    ``WireCostModel.seed`` pre-loads at arm time."""
-    from repro import telemetry
-    from repro.state import wire as wire_mod
-
-    sizes_kb = (64, 256, 1024, 4096)
-    n_pushes = 8
-    fixed = ["exact", "int8"] + [w for w in ("int4", "fp8")
-                                 if w in wire_mod.available_wires()]
-    quant_tiers = tuple(w for w in fixed if w != "exact")
-    curve = {}
-    t = telemetry.enable()
-    cost = wire_mod.enable_cost_model()
-    try:
-        for kb in sizes_kb:
-            n = (kb << 10) // 4
-            rng = np.random.default_rng(kb)
-            updates = [(rng.normal(size=n) * 0.01).astype(np.float32)
-                       for _ in range(n_pushes)]
-            row = {}
-            for wire in fixed + ["auto"]:
-                gt = GlobalTier()
-                gt.set("w", np.zeros(n, np.float32).tobytes(), host="up")
-                lt = LocalTier("h0", gt)
-                lt.wire_tiers = quant_tiers        # candidates for "auto"
-                lt.pull("w")
-                lt.snapshot_base("w")
-                LocalTier("q", gt).pull("w")       # wire interest: frame it
-                view = lt.replica("w").buf.view(np.float32)
-                view[:] += updates[0]
-                lt.push_delta("w", wire=wire)     # warm the kernel/jit path
-                t.drain()                          # discard warm-up spans
-                for u in updates:
-                    view[:] += u
-                    lt.push_delta("w", wire=wire)
-                pushes = [s for s in t.drain() if s.name == "wire.push"]
-                assert len(pushes) == n_pushes, (wire, kb, len(pushes))
-                if wire != "auto":
-                    assert all(s.tags["wire"] == wire for s in pushes)
-                enc_us = sorted(s.tags["encode_ns"] / 1e3 for s in pushes)
-                wall_us = sorted(s.dur * 1e6 for s in pushes)
-                row[wire] = {
-                    "pushes": n_pushes,
-                    "encode_us_p50": enc_us[n_pushes // 2],
-                    "push_us_p50": wall_us[n_pushes // 2],
-                    "bytes_per_push": sum(s.tags["nbytes"]
-                                          for s in pushes) / n_pushes,
-                }
-                if wire == "auto":
-                    row[wire]["wires_chosen"] = sorted(
-                        {s.tags["wire"] for s in pushes})
-            for w in quant_tiers:
-                row[f"encode_ratio_{w}_vs_exact"] = (
-                    row[w]["encode_us_p50"]
-                    / max(row["exact"]["encode_us_p50"], 1e-9))
-                row[f"bytes_ratio_{w}_vs_exact"] = (
-                    row[w]["bytes_per_push"]
-                    / max(row["exact"]["bytes_per_push"], 1e-9))
-            # crossover: bytes saved per extra encode-us = the link MB/s
-            # below which the quantised tier wins end-to-end wall-clock
-            xover = {}
-            for w in quant_tiers:
-                saved = (row["exact"]["bytes_per_push"]
-                         - row[w]["bytes_per_push"])
-                extra_us = (row[w]["push_us_p50"]
-                            - row["exact"]["push_us_p50"])
-                xover[w] = ("inf" if extra_us <= 0.0
-                            else round(saved / extra_us, 1))
-            row["crossover_mbps"] = xover
-            curve[f"{kb}kb"] = row
-    finally:
-        wire_mod.disable_cost_model()
-        telemetry.disable()
-    return {"value_kb": list(sizes_kb), "source": "wire.push spans",
-            "cost_model_samples": cost.samples, **curve}
-
-
-def run_trace() -> None:
-    tr = _bench_codec_trace()
-    for kb in tr["value_kb"]:
-        row = tr[f"{kb}kb"]
-        for w in ("int8", "int4", "fp8"):
-            if w not in row:
-                continue
-            emit(f"codec/encode_{w}_{kb}kb_us", row[w]["encode_us_p50"],
-                 f"{row[f'encode_ratio_{w}_vs_exact']:.1f}x exact encode, "
-                 f"{row[f'bytes_ratio_{w}_vs_exact'] * 100:.0f}% of exact "
-                 f"bytes, wins below {row['crossover_mbps'][w]} MB/s")
-        emit(f"codec/encode_exact_{kb}kb_us", row["exact"]["encode_us_p50"],
-             f"{row['exact']['bytes_per_push'] / 1e6:.2f}MB/push")
-        emit(f"codec/push_auto_{kb}kb_us", row["auto"]["push_us_p50"],
-             f"cost model chose {'/'.join(row['auto']['wires_chosen'])}")
-    with open("BENCH_codec.json", "w") as fh:
-        json.dump(tr, fh, indent=2)
-    big = tr[f"{tr['value_kb'][-1]}kb"]
-    print(f"# codec curve written to BENCH_codec.json (from wire.push "
-          f"spans): at {tr['value_kb'][-1]}KB int8 encode costs "
-          f"{big['encode_ratio_int8_vs_exact']:.1f}x exact for "
-          f"{big['bytes_ratio_int8_vs_exact'] * 100:.0f}% of the bytes; "
-          f"auto picked {'/'.join(big['auto']['wires_chosen'])}")
-
-
 def _bench_faults() -> dict:
     """Failure recovery and degraded-mode throughput (docs/fault_model.md):
     latency from a host kill to the lost call's settle (detect -> requeue
@@ -769,7 +655,5 @@ if __name__ == "__main__":
         run_faults()                               # just the failure rows
     elif "--overload" in sys.argv:
         run_overload()                             # open-loop overload sweep
-    elif "--trace" in sys.argv:
-        run_trace()                                # span-derived codec curve
     else:
         main()

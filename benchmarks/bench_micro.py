@@ -13,8 +13,7 @@ from repro.kernels.flash_attention import flash_attention, attention_ref
 from repro.kernels.decode_attention import decode_attention, decode_attention_ref
 from repro.kernels.ssd_scan import ssd, ssd_ref
 from repro.kernels.moe_gmm import gmm, gmm_ref
-from repro.kernels.state_push import (apply_delta, push, push_ref,
-                                      quantize_delta)
+from repro.kernels.state_push import apply_delta, quantize_delta
 
 RNG = np.random.default_rng(0)
 
@@ -65,13 +64,8 @@ def main() -> None:
     t_rd = time_fn(lambda: g_rd().block_until_ready())
     emit("fig9_micro/moe_gmm", t_rd, f"{t_rd / t_ref:.2f}x vs dense-masked oracle")
 
-    # fused state push
-    a, b, c = _r(1 << 16), _r(1 << 16), _r(1 << 16)
-    p_fused = jax.jit(lambda: push(a, b, c, backend="xla"))
-    t_fused = time_fn(lambda: p_fused().block_until_ready())
-    emit("fig9_micro/state_push_fused", t_fused, "fused delta+apply, 64k f32")
-
     # quantised push wire: encode (quantize_delta) + decode-apply (apply_delta)
+    a, b, c = _r(1 << 16), _r(1 << 16), _r(1 << 16)
     t_q = time_fn(lambda: jax.block_until_ready(
         quantize_delta(a, b, backend="xla")[0]))
     emit("fig9_micro/state_push_quantize", t_q,

@@ -16,9 +16,8 @@ on the installed JAX.  Run from ``scripts/tier1.sh``:
     breakers) fails to resolve, or its disarmed hooks stop compiling out
     to one pointer compare on a policy-less runtime      -> exit 1
   * the host-native wire codec (``state_push.hostcodec``) fails to
-    quantise/conserve for any tier, the int4 nibble packing stops
-    round-tripping, or the disarmed ``WireCostModel`` hook stops
-    compiling out to one pointer compare                 -> exit 1
+    quantise/conserve an int8 delta, or the wire layer stops resolving
+    its two codecs (exact, int8)                         -> exit 1
 
 Invoked standalone:  python scripts/check_jax_pin.py
 """
@@ -195,67 +194,42 @@ def check_overload_entry_points() -> int:
 
 
 def check_wire_entry_points() -> int:
-    """The host-native wire codec and cost model must resolve *without*
-    importing jax — ``LocalTier.push_delta`` takes the hostcodec fast path
-    on every host-resident push, so a drift here is a data-plane outage,
-    not a kernel nicety.  Runs before the jax probes on purpose: importing
+    """The host-native wire codec must resolve *without* importing jax —
+    ``LocalTier.push_delta`` takes the hostcodec fast path on every
+    host-resident push, so a drift here is a data-plane outage, not a
+    kernel nicety.  Runs before the jax probes on purpose: importing
     ``state_push.hostcodec`` must not pull in the device runtime."""
     try:
         import numpy as np
-        from repro.kernels.state_push import hostcodec
+        from repro.kernels.state_push import QMAX, hostcodec
         assert "jax" not in sys.modules, \
             "hostcodec import pulled in jax — host fast path is no longer " \
             "dispatch-free"
 
-        # fused quantise: roundtrip + exact residual conservation per tier
+        # fused int8 quantise: roundtrip + exact residual conservation
         rng = np.random.default_rng(7)
         eff = rng.standard_normal(130).astype(np.float32)
         base = rng.standard_normal(130).astype(np.float32)
         delta = eff - base
-        for qmax in (127, 7):
-            q, s, n, resid = hostcodec.encode_quant(eff, base, qmax=qmax)
-            assert n == 130 and q.shape == (2, 128) and s.shape == (2, 1)
-            deq = hostcodec.decode_rows(q, s, n)
-            assert np.abs(q).max() <= qmax
-            assert np.allclose(deq + resid, delta, atol=1e-6), qmax
-        # int4 nibble packing round-trips the full [-7, 7] code range
-        codes = np.arange(-7, 8, dtype=np.int8)
-        qz = np.zeros((1, 128), np.int8)
-        qz[0, :15] = codes
-        assert np.array_equal(hostcodec.unpack_int4(hostcodec.pack_int4(qz)),
-                              qz)
-        if hostcodec.fp8_available():
-            q, s, n, resid = hostcodec.encode_fp8(eff, base)
-            deq = hostcodec.decode_rows(q, s, n)
-            assert not np.isnan(deq).any()
-            assert np.allclose(deq + resid, delta, atol=1e-6)
+        q, s, n, resid = hostcodec.encode_quant(eff, base)
+        assert n == 130 and q.shape == (2, 128) and s.shape == (2, 1)
+        assert q.dtype == np.int8 and np.abs(q).max() <= QMAX
+        deq = hostcodec.decode_rows(q, s, n)
+        assert np.allclose(deq + resid, delta, atol=1e-6)
 
-        # wire layer: every advertised tier resolves a codec; the cost-model
-        # hook is disarmed at import (one pointer compare per push) and the
-        # enable/disable roundtrip restores that state
+        # wire layer: exactly the two wires, each resolving its codec, and
+        # the policy selects one of them for an f32 value
         from repro.state import wire
-        assert wire._COST is None, "cost model armed at import"
-        for w in wire.available_wires():
+        assert wire.WIRES == ("exact", "int8"), wire.WIRES
+        for w in wire.WIRES:
             assert wire.get_codec(w).name == w
-        assert {"exact", "int8", "int4"} <= set(wire.available_wires())
-        m = wire.enable_cost_model()
-        try:
-            assert wire._COST is m and wire.cost_model() is m
-            assert m.predict("int8", 1 << 16) is None   # no evidence yet
-            m.observe("int8", 1 << 16, 50_000, wall_ns=120_000)
-            assert m.predict("int8", 1 << 16) is not None
-        finally:
-            wire.disable_cost_model()
-        assert wire._COST is None
-        # cost-mode policy: selects a sane wire for an f32 value
-        pol = wire.WirePolicy(tiers=("int8", "int4"))
-        w0 = pol.select(1 << 20, np.float32)
+        w0 = wire.WirePolicy().select(1 << 20, np.float32)
         assert w0 in wire.WIRES, w0
     except Exception as e:
         print(f"check_jax_pin: FAIL — wire codec entry points do not "
               f"resolve: {e!r}\n"
-              f"  LocalTier.push_delta's host fast path, the int4/fp8 tiers "
-              f"and WirePolicy's cost mode depend on these; fix "
+              f"  LocalTier.push_delta's host fast path and WirePolicy "
+              f"depend on these; fix "
               f"src/repro/kernels/state_push/hostcodec.py and "
               f"src/repro/state/wire.py before trusting the tier-1 gate.")
         return 1

@@ -24,9 +24,9 @@ the invariants in ``docs/invariants.md``:
 
 ``wire-construct``
     ``WireFrame(...)`` is constructed only by the codec layer
-    (``repro/state/wire.py``).  Everyone else goes through a
-    ``WireCodec``/``frame_from_quantized`` so frames can't skip residual
-    and version stamping.
+    (``repro/state/wire.py``).  Everyone else encodes through
+    ``wire.get_codec(...)`` so frames can't skip residual and version
+    stamping.
 
 ``tier-copy``
     In the tier files (``state/kv.py``, ``state/local.py``,
@@ -420,8 +420,8 @@ class _FunctionLinter:
                 not self.checker.path_str.endswith(WIRE_HOME):
             self.checker.add(
                 "wire-construct", n.lineno,
-                "WireFrame constructed outside repro/state/wire.py — go "
-                "through a WireCodec (or wire.frame_from_quantized)")
+                "WireFrame constructed outside repro/state/wire.py — "
+                "encode through wire.get_codec(...)")
         if name in _RAW_QUEUE_CALLS and self.checker.bounded_queue_scope:
             self.checker.add(
                 "bounded-queue", n.lineno,

@@ -41,7 +41,6 @@ from repro.core.host_interface import (CallCancelled, DeadlineExceeded,
 from repro.core.proto import ExecutableCache, ProtoFaaslet
 from repro.core.scheduler import LocalScheduler
 from repro.core.vfs import VirtualFS
-from repro.state import wire as _wire_mod
 from repro.state.kv import GlobalTier
 from repro.state.local import LocalTier
 from repro.telemetry import clock as tclock
@@ -1398,23 +1397,6 @@ class FaasmRuntime:
         g("faasm_wire_policy_flips_total",
           "damped WirePolicy wire switches").set(
               sum(t.policy_flips() for t in tiers))
-
-        # wire cost model (docs/observability.md "Wire cost-model gauges"):
-        # disarmed (the default) publishes nothing — one None check
-        cost = _wire_mod._COST
-        if cost is not None:
-            snap = cost.snapshot()
-            g("faasm_wire_cost_samples_total",
-              "encode/transfer observations folded into the model").set(
-                  cost.samples)
-            for wire_name, buckets in snap.items():
-                for bucket, (enc_ns, rest_ns) in buckets.items():
-                    g(f"faasm_wire_cost_{wire_name}_b{bucket}_encode_us",
-                      "EWMA encode cost at 2^b value bytes").set(
-                          enc_ns / 1e3)
-                    g(f"faasm_wire_cost_{wire_name}_b{bucket}_rest_us",
-                      "EWMA non-encode push cost at 2^b value bytes").set(
-                          rest_ns / 1e3)
 
         # overload control plane (docs/observability.md "Overload metrics")
         with self._mutex:

@@ -6,13 +6,14 @@ without jax (``state/wire.py`` imports it at module scope and
 ``scripts/check_jax_pin.py`` exercises it before touching jax).
 """
 
-_OPS = ("apply_delta", "apply_pull", "dequantize", "encode_fp8",
-        "encode_pull", "encode_quant", "push", "quantize_delta",
-        "wire_nbytes")
-_REF = ("apply_delta_ref", "push_ref", "quantize_delta_ref",
-        "quantize_fp8_ref")
+# the int8 wire's largest code: every encode quantises to [-QMAX, QMAX]
+QMAX = 127.0
 
-__all__ = list(_OPS) + list(_REF)
+_OPS = ("apply_delta", "apply_pull", "dequantize", "encode_pull",
+        "encode_quant", "quantize_delta", "wire_nbytes")
+_REF = ("apply_delta_ref", "quantize_delta_ref")
+
+__all__ = ["QMAX"] + list(_OPS) + list(_REF)
 
 
 def __getattr__(name):

@@ -25,27 +25,10 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from repro.kernels.state_push import QMAX
+
 LANES = 128
 DEFAULT_CHUNK_ROWS = 1024  # 512 KB of f32 per chunk — L2-resident on host CPUs
-
-# float8_e4m3fn: max finite 448, no inf — values beyond +-448 cast to NaN, so
-# the encoder must clip codes before the cast.  ml_dtypes ships with jax but
-# the import is gated so a numpy-only environment still gets int8/int4 tiers.
-FP8_MAX = 448.0
-try:
-    from ml_dtypes import float8_e4m3fn as _fp8_dtype
-except ImportError:  # pragma: no cover - ml_dtypes ships with the pinned jax
-    _fp8_dtype = None
-
-
-def fp8_available() -> bool:
-    return _fp8_dtype is not None
-
-
-def fp8_dtype():
-    if _fp8_dtype is None:
-        raise RuntimeError("ml_dtypes not available: fp8 wire tier disabled")
-    return _fp8_dtype
 
 
 def rows_for(numel: int) -> int:
@@ -60,9 +43,9 @@ def _flat_f32(x: np.ndarray) -> np.ndarray:
 
 
 def encode_quant(eff: np.ndarray, base: Optional[np.ndarray] = None, *,
-                 qmax: int = 127, chunk_rows: int = DEFAULT_CHUNK_ROWS,
+                 chunk_rows: int = DEFAULT_CHUNK_ROWS,
                  ) -> Tuple[np.ndarray, np.ndarray, int, np.ndarray]:
-    """Fused quantise of ``eff - base`` to signed codes in ``[-qmax, qmax]``.
+    """Fused quantise of ``eff - base`` to int8 codes in ``[-QMAX, QMAX]``.
 
     ``base=None`` means a zero base (pull-direction encode of a ready-made
     delta) — no zeros array is materialised.  Returns
@@ -79,7 +62,7 @@ def encode_quant(eff: np.ndarray, base: Optional[np.ndarray] = None, *,
     residual = np.empty(rows * LANES, np.float32)
     cr = max(1, min(chunk_rows, rows))
     scratch = np.empty((cr, LANES), np.float32)
-    qmax_f = np.float32(qmax)
+    code_max = np.float32(QMAX)
     eps = np.float32(1e-12)
     for r0 in range(0, rows, cr):
         r1 = min(r0 + cr, rows)
@@ -95,13 +78,13 @@ def encode_quant(eff: np.ndarray, base: Optional[np.ndarray] = None, *,
             flat[m:] = 0.0
         sc = scales[r0:r1]
         np.max(np.abs(ch), axis=1, keepdims=True, out=sc)
-        np.divide(sc, qmax_f, out=sc)
+        np.divide(sc, code_max, out=sc)
         np.maximum(sc, eps, out=sc)
         rch = residual[r0 * LANES: r1 * LANES].reshape(r1 - r0, LANES)
         np.copyto(rch, ch)                      # stash delta
         np.divide(ch, sc, out=ch)
         np.rint(ch, out=ch)
-        np.clip(ch, -qmax_f, qmax_f, out=ch)
+        np.clip(ch, -code_max, code_max, out=ch)
         qc = q[r0:r1]
         qc[...] = ch                            # integral f32 -> int8
         np.multiply(qc, sc, out=ch)             # dequantised carry
@@ -109,84 +92,17 @@ def encode_quant(eff: np.ndarray, base: Optional[np.ndarray] = None, *,
     return q, scales, n, residual[:n]
 
 
-def encode_fp8(eff: np.ndarray, base: Optional[np.ndarray] = None, *,
-               chunk_rows: int = DEFAULT_CHUNK_ROWS,
-               ) -> Tuple[np.ndarray, np.ndarray, int, np.ndarray]:
-    """Fused fp8 (e4m3fn) encode of ``eff - base`` (``base=None`` → zero base).
-
-    Returns ``(q fp8 (R,128), scales f32 (R,1), numel, residual f32 (numel,))``.
-    Codes are clipped to ±``FP8_MAX`` *before* the cast — e4m3fn has no inf,
-    so an unclipped overflow would silently become NaN on the wire.
-    """
-    dt = fp8_dtype()
-    eff_f = _flat_f32(eff)
-    base_f = _flat_f32(base) if base is not None else None
-    n = eff_f.size
-    rows = rows_for(n)
-    q = np.empty((rows, LANES), dt)
-    scales = np.empty((rows, 1), np.float32)
-    residual = np.empty(rows * LANES, np.float32)
-    cr = max(1, min(chunk_rows, rows))
-    scratch = np.empty((cr, LANES), np.float32)
-    fmax = np.float32(FP8_MAX)
-    eps = np.float32(1e-12)
-    for r0 in range(0, rows, cr):
-        r1 = min(r0 + cr, rows)
-        i0, i1 = r0 * LANES, min(r1 * LANES, n)
-        m = i1 - i0
-        ch = scratch[: r1 - r0]
-        flat = ch.reshape(-1)
-        if base_f is None:
-            np.copyto(flat[:m], eff_f[i0:i1])
-        else:
-            np.subtract(eff_f[i0:i1], base_f[i0:i1], out=flat[:m])
-        if m < flat.size:
-            flat[m:] = 0.0
-        sc = scales[r0:r1]
-        np.max(np.abs(ch), axis=1, keepdims=True, out=sc)
-        np.divide(sc, fmax, out=sc)
-        np.maximum(sc, eps, out=sc)
-        rch = residual[r0 * LANES: r1 * LANES].reshape(r1 - r0, LANES)
-        np.copyto(rch, ch)
-        np.divide(ch, sc, out=ch)
-        np.clip(ch, -fmax, fmax, out=ch)
-        qc = q[r0:r1]
-        qc[...] = ch                            # f32 -> fp8 (rounds to e4m3fn)
-        np.multiply(qc.astype(np.float32), sc, out=ch)
-        np.subtract(rch, ch, out=rch)
-    return q, scales, n, residual[:n]
-
-
 def decode_rows(payload: np.ndarray, scales: np.ndarray, numel: int
                 ) -> np.ndarray:
-    """Decode a (R,128) payload (int8 or fp8) back to the flat f32 delta."""
+    """Decode a (R,128) int8 payload back to the flat f32 delta."""
     return (payload.astype(np.float32) * scales).reshape(-1)[:numel]
-
-
-def pack_int4(q: np.ndarray) -> np.ndarray:
-    """Pack (R,128) int8 codes in [-7,7] into (R,64) uint8 nibble pairs.
-
-    Lane 2k goes to the low nibble, lane 2k+1 to the high nibble."""
-    lo = q[:, 0::2].astype(np.uint8) & 0x0F
-    hi = (q[:, 1::2].astype(np.uint8) & 0x0F) << 4
-    return lo | hi
-
-
-def unpack_int4(packed: np.ndarray) -> np.ndarray:
-    """Inverse of :func:`pack_int4`: (R,64) uint8 → (R,128) int8 in [-8,7]."""
-    rows = packed.shape[0]
-    q = np.empty((rows, 2 * packed.shape[1]), np.int8)
-    # shift-left-then-arithmetic-shift-right sign-extends the nibble
-    q[:, 0::2] = (packed << 4).astype(np.int8) >> 4
-    q[:, 1::2] = packed.astype(np.int8) >> 4
-    return q
 
 
 def encode_exact(eff: np.ndarray, base: np.ndarray, *,
                  chunk_rows: int = DEFAULT_CHUNK_ROWS) -> np.ndarray:
     """Chunked exact delta: flat f32 ``eff - base`` into a fresh buffer.
 
-    The chunk loop exists for symmetry with the quantised encoders — each
+    The chunk loop exists for symmetry with the quantised encoder — each
     completed chunk of the output is final while later chunks encode."""
     eff_f = _flat_f32(eff)
     base_f = _flat_f32(base)

@@ -15,33 +15,21 @@ VPU runs at full occupancy; rows are the streaming dimension.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.state_push import QMAX
+
 LANES = 128
 
 
-def _quantize_kernel(local_ref, base_ref, q_ref, scale_ref, *, qmax: float):
+def _quantize_kernel(local_ref, base_ref, q_ref, scale_ref):
     delta = local_ref[...].astype(jnp.float32) - base_ref[...].astype(jnp.float32)
     absmax = jnp.max(jnp.abs(delta), axis=1, keepdims=True)
-    scale = jnp.maximum(absmax / qmax, 1e-12)
-    q_ref[...] = jnp.clip(jnp.round(delta / scale), -qmax, qmax).astype(jnp.int8)
-    scale_ref[...] = scale
-
-
-FP8_MAX = 448.0  # float8_e4m3fn max finite — clip before cast (no inf in e4m3)
-
-
-def _quantize_fp8_kernel(local_ref, base_ref, q_ref, scale_ref):
-    delta = local_ref[...].astype(jnp.float32) - base_ref[...].astype(jnp.float32)
-    absmax = jnp.max(jnp.abs(delta), axis=1, keepdims=True)
-    scale = jnp.maximum(absmax / FP8_MAX, 1e-12)
-    q_ref[...] = jnp.clip(delta / scale, -FP8_MAX,
-                          FP8_MAX).astype(jnp.float8_e4m3fn)
+    scale = jnp.maximum(absmax / QMAX, 1e-12)
+    q_ref[...] = jnp.clip(jnp.round(delta / scale), -QMAX, QMAX).astype(jnp.int8)
     scale_ref[...] = scale
 
 
@@ -51,43 +39,19 @@ def _apply_kernel(global_ref, q_ref, scale_ref, out_ref):
                     ).astype(out_ref.dtype)
 
 
-def _push_kernel(local_ref, base_ref, global_ref, out_ref):
-    out_ref[...] = (global_ref[...].astype(jnp.float32)
-                    + local_ref[...].astype(jnp.float32)
-                    - base_ref[...].astype(jnp.float32)).astype(out_ref.dtype)
-
-
 def quantize_delta_pallas(local, base, *, block_rows: int = 256,
-                          interpret: bool = False, qmax: float = 127.0):
+                          interpret: bool = False):
     R, L = local.shape
     assert L == LANES and R % block_rows == 0, (local.shape, block_rows)
     grid = (R // block_rows,)
     spec = pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))
     sspec = pl.BlockSpec((block_rows, 1), lambda i: (i, 0))
     return pl.pallas_call(
-        functools.partial(_quantize_kernel, qmax=qmax),
+        _quantize_kernel,
         grid=grid,
         in_specs=[spec, spec],
         out_specs=[spec, sspec],
         out_shape=[jax.ShapeDtypeStruct((R, LANES), jnp.int8),
-                   jax.ShapeDtypeStruct((R, 1), jnp.float32)],
-        interpret=interpret,
-    )(local, base)
-
-
-def quantize_fp8_pallas(local, base, *, block_rows: int = 256,
-                        interpret: bool = False):
-    R, L = local.shape
-    assert L == LANES and R % block_rows == 0, (local.shape, block_rows)
-    grid = (R // block_rows,)
-    spec = pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))
-    sspec = pl.BlockSpec((block_rows, 1), lambda i: (i, 0))
-    return pl.pallas_call(
-        _quantize_fp8_kernel,
-        grid=grid,
-        in_specs=[spec, spec],
-        out_specs=[spec, sspec],
-        out_shape=[jax.ShapeDtypeStruct((R, LANES), jnp.float8_e4m3fn),
                    jax.ShapeDtypeStruct((R, 1), jnp.float32)],
         interpret=interpret,
     )(local, base)
@@ -109,18 +73,3 @@ def apply_delta_pallas(global_val, q, scale, *, block_rows: int = 256,
         interpret=interpret,
     )(global_val, q, scale)
 
-
-def push_pallas(local, base, global_val, *, block_rows: int = 256,
-                interpret: bool = False):
-    R, L = local.shape
-    assert L == LANES and R % block_rows == 0
-    grid = (R // block_rows,)
-    spec = pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))
-    return pl.pallas_call(
-        _push_kernel,
-        grid=grid,
-        in_specs=[spec, spec, spec],
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct(global_val.shape, global_val.dtype),
-        interpret=interpret,
-    )(local, base, global_val)

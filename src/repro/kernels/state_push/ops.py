@@ -13,7 +13,7 @@ Two encode paths, chosen per call:
   jitted executable per backend: on ``xla`` flatten + pad + quantise +
   residual (``_encode_fused``), on Pallas flatten + pad + the quantise
   kernel + the f32 delta rows (``_encode_pallas``).  jax compiles each once
-  per ``(shape, dtype, qmax)`` and caches it.  On ``xla`` large values are
+  per ``(shape, dtype)`` and caches it.  On ``xla`` large values are
   encoded in row chunks whose copy-out is pipelined with the next chunk's
   dispatch — async dispatch means chunk N quantises on device while chunk
   N−1's payload is crossing to the host.
@@ -36,15 +36,12 @@ from repro.kernels.common import resolve_backend, round_up
 from repro.kernels.state_push import hostcodec
 from repro.kernels.state_push import ref as _ref
 from repro.kernels.state_push.kernel import (LANES, apply_delta_pallas,
-                                             push_pallas,
-                                             quantize_delta_pallas,
-                                             quantize_fp8_pallas)
+                                             quantize_delta_pallas)
 
 # the xla path is the hot CPU-host wire codec (LocalTier.push_delta calls it
 # per push): jit once, jax caches the executable per shape
-_quantize_ref = jax.jit(_ref.quantize_delta_ref, static_argnums=(2,))
+_quantize_ref = jax.jit(_ref.quantize_delta_ref)
 _apply_ref = jax.jit(_ref.apply_delta_ref)
-_push_ref = jax.jit(_ref.push_ref)
 
 # rows a device-side encode processes per dispatch when chunking: 2 MB of f32
 # keeps enough compute in flight to hide each chunk's host copy-out
@@ -60,52 +57,39 @@ def _to_rows(x):
 
 
 @functools.partial(jax.jit, static_argnums=(0,),
-                   static_argnames=("interpret", "qmax"))
-def _pallas_rows(kernel, *operands, interpret: bool, **kw):
+                   static_argnames=("interpret",))
+def _pallas_rows(kernel, *operands, interpret: bool):
     """Run a row-streaming state-push kernel over (R, ·) operands.
 
     Blocks are (8k, 128) tiles of at most 256 rows, as Mosaic requires, so
     the rows are zero-padded to a whole number of blocks.  Zero rows
     quantise to zero codes and apply as a no-op; every output is trimmed
-    back to R rows.  jax compiles it once per kernel, operand shapes and
-    static parameters."""
+    back to R rows.  jax compiles it once per kernel and operand shapes."""
     rows = operands[0].shape[0]
     blk = min(256, round_up(rows, 8))
     pad = round_up(rows, blk) - rows
     out = kernel(*(jnp.pad(x, ((0, pad), (0, 0))) for x in operands),
-                 block_rows=blk, interpret=interpret, **kw)
+                 block_rows=blk, interpret=interpret)
     if isinstance(out, (list, tuple)):
         return tuple(o[:rows] for o in out)
     return out[:rows]
 
 
-@functools.partial(jax.jit, static_argnames=("qmax", "with_residual"))
-def _encode_fused(local, base, qmax, with_residual):
-    """Single-dispatch device encode: flatten/pad/quantise (+ residual) in one
-    executable.  jax caches the compiled program per (shape, dtype, qmax)."""
-    lr, _ = _to_rows(local)
-    br, _ = _to_rows(base)
-    q, s = _ref.quantize_delta_ref(lr, br, float(qmax))
-    if not with_residual:
-        return q, s
-    resid = (lr - br) - q.astype(jnp.float32) * s
-    return q, s, resid
-
-
 @functools.partial(jax.jit, static_argnames=("with_residual",))
-def _encode_fp8_fused(local, base, with_residual):
+def _encode_fused(local, base, with_residual):
+    """Single-dispatch device encode: flatten/pad/quantise (+ residual) in one
+    executable.  jax caches the compiled program per (shape, dtype)."""
     lr, _ = _to_rows(local)
     br, _ = _to_rows(base)
-    q, s = _ref.quantize_fp8_ref(lr, br)
+    q, s = _ref.quantize_delta_ref(lr, br)
     if not with_residual:
         return q, s
     resid = (lr - br) - q.astype(jnp.float32) * s
     return q, s, resid
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("qmax", "fp8", "interpret", "with_residual"))
-def _encode_pallas(local, base, qmax, fp8, interpret, with_residual):
+@functools.partial(jax.jit, static_argnames=("interpret", "with_residual"))
+def _encode_pallas(local, base, interpret, with_residual):
     """Pallas twin of :func:`_encode_fused`: flatten/pad and the quantise
     kernel in one executable, returning the codes, the scales and (with
     ``with_residual``) the f32 delta rows ``lr − br``.  The residual itself
@@ -113,17 +97,13 @@ def _encode_pallas(local, base, qmax, fp8, interpret, with_residual):
     multiply-subtract."""
     lr, _ = _to_rows(local)
     br, _ = _to_rows(base)
-    if fp8:
-        q, s = _pallas_rows(quantize_fp8_pallas, lr, br, interpret=interpret)
-    else:
-        q, s = _pallas_rows(quantize_delta_pallas, lr, br,
-                            interpret=interpret, qmax=float(qmax))
+    q, s = _pallas_rows(quantize_delta_pallas, lr, br, interpret=interpret)
     if not with_residual:
         return q, s
     return q, s, lr - br
 
 
-def _device_encode(eff, base, *, qmax, fp8, b, with_residual):
+def _device_encode(eff, base, *, b, with_residual):
     """Device-path encode returning host numpy wire buffers.
 
     Each backend takes one cached executable per shape: ``_encode_pallas``
@@ -136,8 +116,8 @@ def _device_encode(eff, base, *, qmax, fp8, b, with_residual):
     n = int(np.prod(np.shape(eff))) if np.shape(eff) else 1
     rows = hostcodec.rows_for(n)
     if b != "xla":
-        out = _encode_pallas(eff, base, float(qmax), fp8,
-                             b == "pallas_interpret", with_residual)
+        out = _encode_pallas(eff, base, b == "pallas_interpret",
+                             with_residual)
         qn, sn = np.asarray(out[0]), np.asarray(out[1])
         if not with_residual:
             return qn, sn, n, None
@@ -145,8 +125,7 @@ def _device_encode(eff, base, *, qmax, fp8, b, with_residual):
         resid = deltar - qn.astype(np.float32) * sn
         return qn, sn, n, resid.reshape(-1)[:n]
     if rows <= DEVICE_CHUNK_ROWS:
-        out = (_encode_fp8_fused(eff, base, with_residual) if fp8
-               else _encode_fused(eff, base, qmax, with_residual))
+        out = _encode_fused(eff, base, with_residual)
         if with_residual:
             q, s, resid = out
             return (np.asarray(q), np.asarray(s), n,
@@ -160,11 +139,8 @@ def _device_encode(eff, base, *, qmax, fp8, b, with_residual):
     for r0 in range(0, rows, DEVICE_CHUNK_ROWS):
         r1 = min(r0 + DEVICE_CHUNK_ROWS, rows)
         parts.append((r0, r1,
-                      _encode_fp8_fused(lr[r0:r1], br[r0:r1], with_residual)
-                      if fp8 else
-                      _encode_fused(lr[r0:r1], br[r0:r1], qmax, with_residual)))
-    qdt = hostcodec.fp8_dtype() if fp8 else np.int8
-    qn = np.empty((rows, LANES), qdt)
+                      _encode_fused(lr[r0:r1], br[r0:r1], with_residual)))
+    qn = np.empty((rows, LANES), np.int8)
     sn = np.empty((rows, 1), np.float32)
     resid = np.empty(rows * LANES, np.float32) if with_residual else None
     for r0, r1, out in parts:
@@ -178,11 +154,11 @@ def _device_encode(eff, base, *, qmax, fp8, b, with_residual):
     return qn, sn, n, (resid[:n] if with_residual else None)
 
 
-def encode_quant(eff, base, *, qmax: int = 127, backend: str | None = None,
+def encode_quant(eff, base, *, backend: str | None = None,
                  with_residual: bool = True):
-    """Fused wire encode for the integer tiers: quantise ``eff − base`` to
-    signed codes in ``[-qmax, qmax]`` and (optionally) the error-feedback
-    residual, in one pass.  Returns host numpy
+    """Fused int8 wire encode: quantise ``eff − base`` to codes in
+    ``[-QMAX, QMAX]`` and (optionally) the error-feedback residual, in one
+    pass.  Returns host numpy
     ``(q int8 (R,128), scales f32 (R,1), numel, residual f32 (numel,) | None)``.
 
     Host-resident numpy operands on the ``xla`` backend skip JAX entirely
@@ -191,44 +167,27 @@ def encode_quant(eff, base, *, qmax: int = 127, backend: str | None = None,
     b = resolve_backend(backend)
     if b == "xla" and (base is None or hostcodec.usable(eff, base)) \
             and isinstance(eff, np.ndarray):
-        q, s, n, resid = hostcodec.encode_quant(eff, base, qmax=qmax)
+        q, s, n, resid = hostcodec.encode_quant(eff, base)
         return q, s, n, (resid if with_residual else None)
     if base is None:
         base = jnp.zeros_like(jnp.ravel(eff))
-    return _device_encode(eff, base, qmax=qmax, fp8=False, b=b,
-                          with_residual=with_residual)
+    return _device_encode(eff, base, b=b, with_residual=with_residual)
 
 
-def encode_fp8(eff, base, *, backend: str | None = None,
-               with_residual: bool = True):
-    """fp8 (e4m3fn) twin of :func:`encode_quant` — same path selection."""
-    b = resolve_backend(backend)
-    if b == "xla" and (base is None or hostcodec.usable(eff, base)) \
-            and isinstance(eff, np.ndarray):
-        q, s, n, resid = hostcodec.encode_fp8(eff, base)
-        return q, s, n, (resid if with_residual else None)
-    if base is None:
-        base = jnp.zeros_like(jnp.ravel(eff))
-    return _device_encode(eff, base, qmax=0, fp8=True, b=b,
-                          with_residual=with_residual)
-
-
-def quantize_delta(local, base, *, backend: str | None = None,
-                   qmax: int = 127):
+def quantize_delta(local, base, *, backend: str | None = None):
     """Any-shape fused delta quantisation.  Returns (q (R,128) int8, scales (R,1),
     original_numel) — the wire format of a compressed push."""
     b = resolve_backend(backend)
     if b == "xla" and hostcodec.usable(local, base):
-        q, s, n, _ = hostcodec.encode_quant(local, base, qmax=qmax)
+        q, s, n, _ = hostcodec.encode_quant(local, base)
         return q, s, n
     lr, n = _to_rows(local)
     br, _ = _to_rows(base)
     if b == "xla":
-        q, s = _quantize_ref(lr, br, float(qmax))
+        q, s = _quantize_ref(lr, br)
     else:
         q, s = _pallas_rows(quantize_delta_pallas, lr, br,
-                            interpret=(b == "pallas_interpret"),
-                            qmax=float(qmax))
+                            interpret=(b == "pallas_interpret"))
     return q, s, n
 
 
@@ -287,17 +246,3 @@ def apply_pull(value, q, scales, *, backend: str | None = None):
     boundary."""
     return _apply_wire(value, q, scales, backend)
 
-
-def push(local, base, global_val, *, backend: str | None = None):
-    """Uncompressed fused push: global += local - base (any shape)."""
-    b = resolve_backend(backend)
-    shape, dtype = global_val.shape, global_val.dtype
-    lr, n = _to_rows(local)
-    br, _ = _to_rows(base)
-    gr, _ = _to_rows(global_val)
-    if b == "xla":
-        out = _push_ref(lr, br, gr)
-    else:
-        out = _pallas_rows(push_pallas, lr, br, gr,
-                           interpret=(b == "pallas_interpret"))
-    return out.reshape(-1)[:n].reshape(shape).astype(dtype)

@@ -8,31 +8,15 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.kernels.state_push import QMAX
 
-def quantize_delta_ref(local, base, qmax: float = 127.0):
-    """local/base: (R, 128) f32.  Returns (q int8 (R,128), scales f32 (R, 1)).
 
-    ``qmax`` selects the integer tier: 127 for the int8 wire, 7 for the int4
-    wire (codes stay int8 here; nibble-packing is a host-side wire concern)."""
+def quantize_delta_ref(local, base):
+    """local/base: (R, 128) f32.  Returns (q int8 (R,128), scales f32 (R, 1))."""
     delta = local.astype(jnp.float32) - base.astype(jnp.float32)
     absmax = jnp.max(jnp.abs(delta), axis=1, keepdims=True)
-    scale = jnp.maximum(absmax / qmax, 1e-12)
-    q = jnp.clip(jnp.round(delta / scale), -qmax, qmax).astype(jnp.int8)
-    return q, scale
-
-
-FP8_MAX = 448.0  # float8_e4m3fn max finite; no inf — overflow casts to NaN
-
-
-def quantize_fp8_ref(local, base):
-    """fp8 (e4m3fn) twin of :func:`quantize_delta_ref`.
-
-    Codes are clipped to ±``FP8_MAX`` before the cast: e4m3fn has no inf, so
-    an unclipped |code| > 448 would become NaN on the wire."""
-    delta = local.astype(jnp.float32) - base.astype(jnp.float32)
-    absmax = jnp.max(jnp.abs(delta), axis=1, keepdims=True)
-    scale = jnp.maximum(absmax / FP8_MAX, 1e-12)
-    q = jnp.clip(delta / scale, -FP8_MAX, FP8_MAX).astype(jnp.float8_e4m3fn)
+    scale = jnp.maximum(absmax / QMAX, 1e-12)
+    q = jnp.clip(jnp.round(delta / scale), -QMAX, QMAX).astype(jnp.int8)
     return q, scale
 
 
@@ -40,9 +24,3 @@ def apply_delta_ref(global_val, q, scale):
     """global_val: (R, 128); q: (R,128) int8; scale: (R,1).  Returns new global."""
     return (global_val.astype(jnp.float32)
             + q.astype(jnp.float32) * scale).astype(global_val.dtype)
-
-
-def push_ref(local, base, global_val):
-    """Uncompressed fused push: global += (local - base)."""
-    delta = local.astype(jnp.float32) - base.astype(jnp.float32)
-    return (global_val.astype(jnp.float32) + delta).astype(global_val.dtype)

@@ -52,8 +52,7 @@ import numpy as np
 from repro import overload as oload
 from repro.analysis.annotations import holds_stripe
 from repro.analysis.sanitizer import make_mutex, wrap_rwlock
-from repro.state import wire as _wire_mod
-from repro.state.wire import WireFrame, frame_from_quantized, get_codec
+from repro.state.wire import WireFrame, get_codec
 from repro.telemetry import clock as _clock
 
 # repro.analysis.sanitizer installs its hook state here (enable()); None
@@ -661,14 +660,6 @@ class GlobalTier:
                 s.copied += wire
         return wire
 
-    def apply_quantized(self, key: str, q: np.ndarray, scales: np.ndarray,
-                        numel: int, *, dtype=np.float32,
-                        host: str = "?") -> int:
-        """Apply an int8-quantised delta push (the ``kernels/state_push``
-        wire tuple) — compatibility front over :meth:`apply_wire`."""
-        frame = frame_from_quantized(q, scales, numel, dtype=dtype)
-        return self.apply_wire(key, frame, host=host)
-
     def pull_wire(self, key: str, base_version: int, *, wire: str = "int8",
                   dtype=np.float32, residual: Optional[np.ndarray] = None,
                   exclude_origin: Optional[str] = None,
@@ -719,10 +710,7 @@ class GlobalTier:
         # int8 re-encode (a fused-kernel dispatch) are full-value work that
         # must not serialise unrelated keys in the stripe behind it
         tel = _TEL
-        cost = _wire_mod._COST
-        timed = tel is not None or cost is not None
         span = tel.begin("wire.pull", "wire") if tel is not None else None
-        w0 = _clock.now_ns() if timed else 0
         numel = max(f.numel for f in served)
         delta = np.zeros(numel, np.float32)
         for f in served:
@@ -730,9 +718,9 @@ class GlobalTier:
             delta[:d.size] += d
         if residual is not None and residual.size == delta.size:
             delta = delta + residual
-        enc0 = _clock.now_ns() if timed else 0
+        enc0 = _clock.now_ns() if tel is not None else 0
         frame = get_codec(wire).encode_delta(delta, backend=backend)
-        enc_ns = _clock.now_ns() - enc0 if timed else 0
+        enc_ns = _clock.now_ns() - enc0 if tel is not None else 0
         new_residual = None
         if frame.wire != "exact":
             new_residual = delta - frame.decode()
@@ -742,11 +730,6 @@ class GlobalTier:
         with s.lock:
             s.pulled[host] = s.pulled.get(host, 0) + frame.nbytes
             s.copied += frame.nbytes
-        if cost is not None:
-            # pull-direction evidence: the re-encode is the same codec work
-            # a push pays, so it feeds the same per-(wire, size) curve
-            cost.observe(frame.wire, frame.numel * 4, enc_ns,
-                         wall_ns=_clock.now_ns() - w0)
         if tel is not None:
             tel.end(span, key=key,
                     wire=frame.wire, nbytes=frame.nbytes,

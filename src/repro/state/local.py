@@ -20,8 +20,8 @@ bumps ``Replica.version``; the device copy records the version it was
 synced at, so a stale device array is never silently pushed.
 
 Symmetric wire fabric (``repro.state.wire``): every delta crossing the tier
-boundary is a :class:`~repro.state.wire.WireFrame` encoded by a
-:class:`~repro.state.wire.WireCodec` — identically in both directions.
+boundary is a :class:`~repro.state.wire.WireFrame` encoded by the exact
+or the int8 codec (``wire.get_codec``) — identically in both directions.
 
   * **Push** — ``push_delta(wire="int8")`` runs the fused
     ``kernels/state_push`` quantise kernel on the pusher (device-native when
@@ -40,13 +40,10 @@ boundary is a :class:`~repro.state.wire.WireFrame` encoded by a
     device arrays via ``ops.apply_pull``), converging with zero pull bytes.
 
 ``wire="auto"`` (or ``None``) delegates the choice to the key's
-:class:`~repro.state.wire.WirePolicy`: with the
-:class:`~repro.state.wire.WireCostModel` armed it argmins the measured
-per-size end-to-end push cost over ``exact`` and the residual-qualified
-tiers in ``wire_tiers`` (the opt-in menu — ``set_wire_tiers("int8",
-"int4", "fp8")``); disarmed, the historic exact-vs-quantised vote from
+:class:`~repro.state.wire.WirePolicy`: an int8-vs-exact vote from the
 observed delta magnitude/density and residual norm, with flip-flop
-damping.  Explicit ``wire=`` strings remain as overrides.
+damping.  Explicit ``wire=`` strings (``"exact"`` or ``"int8"``) remain
+as overrides.
 """
 from __future__ import annotations
 
@@ -58,17 +55,13 @@ import numpy as np
 
 from repro import faults
 from repro.analysis.sanitizer import make_mutex, wrap_rwlock
-from repro.state import wire as _wire_mod
+from repro.kernels.state_push import QMAX
 from repro.state.kv import GlobalTier, RWLock
 from repro.state.wire import (INT8_WIRE_MIN_BYTES, WIRES, WireFrame,
                               WirePolicy, get_codec)
 from repro.telemetry import clock as _clock
 
 __all__ = ["DeviceReplica", "INT8_WIRE_MIN_BYTES", "LocalTier", "Replica"]
-
-# per-wire maximum |code|: absmax ≈ scale·QMAX reconstructs the delta absmax
-# from the wire tuple without a second full-array pass
-_WIRE_QMAX = {"int8": 127.0, "int4": 7.0, "fp8": 448.0}
 
 
 class CodecFallback(Exception):
@@ -157,10 +150,6 @@ class LocalTier:
         self._subscribed: Set[str] = set()
         self._mutex = make_mutex("tier", f"tier:{host_id}")
         self.codec_fallbacks = 0     # quantised encodes rescued by exact wire
-        # quantised tiers the per-key policies may choose from; the narrow
-        # int4/fp8 tiers are opt-in (set_wire_tiers) — their coarser codes
-        # ride the same residual_cap error-feedback discipline
-        self.wire_tiers = ("int8",)
 
     # -- replica lifecycle ------------------------------------------------------
 
@@ -328,18 +317,8 @@ class LocalTier:
         with self._mutex:
             p = self._policies.get(key)
             if p is None:
-                p = self._policies[key] = WirePolicy(tiers=self.wire_tiers)
+                p = self._policies[key] = WirePolicy()
             return p
-
-    def set_wire_tiers(self, *tiers: str) -> None:
-        """Opt this tier's keys into a different quantised-tier menu (e.g.
-        ``set_wire_tiers("int8", "int4")``).  Existing per-key policies are
-        rebuilt — learned selection state restarts from the defaults."""
-        for t in tiers:
-            get_codec(t)                 # unknown/unavailable wires fail loud
-        self.wire_tiers = tuple(tiers)
-        with self._mutex:
-            self._policies.clear()
 
     def policy_flips(self) -> int:
         """Total damped wire switches across this tier's per-key policies
@@ -442,8 +421,7 @@ class LocalTier:
                          if int(d.value.size) == frame.numel else None)
                 if codes is not None:
                     # quantised frame onto a device value: the fused kernel
-                    # applies q·scale on device — no host round-trip (int4
-                    # arrives nibble-unpacked, fp8 casts in-kernel)
+                    # applies q·scale on device — no host round-trip
                     from repro.kernels.state_push import ops
                     d.value = ops.apply_pull(d.value, codes[0], codes[1],
                                              backend=backend)
@@ -797,12 +775,17 @@ class LocalTier:
             wire = self.wire_policy(key).select(r.buf.size, dt)
         if wire not in WIRES:
             raise ValueError(f"wire {wire!r} not in {WIRES + ('auto',)}")
-        exact_framed = (dt == np.float32 and gt.delta_window > 0
-                        and gt.wire_interest(key, exclude=self.origin_id))
-        if (wire != "exact" and dt.kind == "f"
+        d = r.device
+        # the in-place path reads only the host buffer: a fresh device copy
+        # (which may hold device-side writes) ships as an exact frame
+        exact_framed = dt == np.float32 and (
+            (d is not None and d.fresh(r))
+            or (gt.delta_window > 0
+                and gt.wire_interest(key, exclude=self.origin_id)))
+        if (wire == "int8" and dt.kind == "f"
                 and r.buf.size >= INT8_WIRE_MIN_BYTES):
             try:
-                moved = self._push_delta_quant(key, r, dt, backend, wire=wire,
+                moved = self._push_delta_quant(key, r, dt, backend,
                                                auto=auto, fence=fence)
             except CodecFallback:
                 # the quantised encode failed before any tier effect: the
@@ -892,10 +875,8 @@ class LocalTier:
         gt = self.global_tier
         codec = get_codec("exact")
         tel = _TEL
-        cost = _wire_mod._COST
-        timed = tel is not None or cost is not None
         span = tel.begin("wire.push", "wire") if tel is not None else None
-        enc0 = _clock.now_ns() if timed else 0
+        enc0 = _clock.now_ns() if tel is not None else 0
         r.lock.acquire_write()
         try:
             snap = None
@@ -939,7 +920,7 @@ class LocalTier:
                 r.dirty_chunks.clear()
         finally:
             r.lock.release_write()
-        enc_ns = (_clock.now_ns() - enc0) if timed else 0
+        enc_ns = (_clock.now_ns() - enc0) if tel is not None else 0
         lock = gt.lock(key)
         lock.acquire_write()
         try:
@@ -959,9 +940,6 @@ class LocalTier:
                         encode_ns=enc_ns, origin=self.origin_id)
             return 0
         self._after_push(key, r, frame)
-        if cost is not None:
-            cost.observe(frame.wire, frame.numel * 4, enc_ns,
-                         wall_ns=_clock.now_ns() - enc0)
         if tel is not None:
             tel.end(span, key=key,
                     wire=frame.wire, nbytes=frame.nbytes,
@@ -974,29 +952,25 @@ class LocalTier:
             delta = frame.payload
             self.wire_policy(key).observe(
                 delta_absmax=float(np.abs(delta).max()) if delta.size else 0.0,
-                density=float(np.count_nonzero(delta)) / max(delta.size, 1),
-                wire=frame.wire)
+                density=float(np.count_nonzero(delta)) / max(delta.size, 1))
         return moved
 
     def _push_delta_quant(self, key: str, r: Replica, dt: np.dtype,
-                          backend: Optional[str], *, wire: str = "int8",
-                          auto: bool = False,
+                          backend: Optional[str], *, auto: bool = False,
                           fence: Optional[tuple] = None) -> int:
-        """Quantised delta push (int8 / int4 / fp8): encode under the
-        replica write lock, apply under the key's global write lock,
-        broadcast with no locks held.
+        """Quantised delta push (int8): encode under the replica write lock,
+        apply under the key's global write lock, broadcast with no locks
+        held.
 
         Device-native when the replica has a fresh device copy: quantise
         runs on ``DeviceReplica.value``/``base`` and only the wire frame
         comes back to the host.  Otherwise the host replica buffer feeds
         the host-native fused codec directly (no JAX dispatch)."""
         gt = self.global_tier
-        codec = get_codec(wire)
+        codec = get_codec("int8")
         tel = _TEL
-        cost = _wire_mod._COST
-        timed = tel is not None or cost is not None
         span = tel.begin("wire.push", "wire") if tel is not None else None
-        enc0 = _clock.now_ns() if timed else 0
+        enc0 = _clock.now_ns() if tel is not None else 0
         r.lock.acquire_write()
         try:
             snap = None
@@ -1053,7 +1027,7 @@ class LocalTier:
                 r.dirty_chunks.clear()
         finally:
             r.lock.release_write()
-        enc_ns = (_clock.now_ns() - enc0) if timed else 0
+        enc_ns = (_clock.now_ns() - enc0) if tel is not None else 0
         lock = gt.lock(key)
         lock.acquire_write()
         try:
@@ -1073,9 +1047,6 @@ class LocalTier:
                         encode_ns=enc_ns, origin=self.origin_id)
             return 0
         self._after_push(key, r, frame)
-        if cost is not None:
-            cost.observe(frame.wire, frame.numel * 4, enc_ns,
-                         wall_ns=_clock.now_ns() - enc0)
         if tel is not None:
             tel.end(span, key=key,
                     wire=frame.wire, nbytes=frame.nbytes,
@@ -1092,11 +1063,10 @@ class LocalTier:
             carried = float((qf.mean(axis=1) * sc[:, 0]).mean()) if q.size \
                 else 0.0
             self.wire_policy(key).observe(
-                delta_absmax=(float(sc.max()) * _WIRE_QMAX[frame.wire]
+                delta_absmax=(float(sc.max()) * QMAX
                               if sc is not None and sc.size else 0.0),
                 density=float(np.count_nonzero(qf)) / max(q.size, 1),
-                residual_ratio=_mean_abs(residual) / (carried + 1e-12),
-                wire=frame.wire)
+                residual_ratio=_mean_abs(residual) / (carried + 1e-12))
         return moved
 
     @staticmethod

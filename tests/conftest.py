@@ -47,15 +47,6 @@ def _telemetry_disarmed():
 
 
 @pytest.fixture(autouse=True)
-def _cost_model_disarmed():
-    """Safety net: a WireCostModel armed by one test never leaks into the
-    next (a leaked model flips every WirePolicy into cost mode)."""
-    from repro.state import wire
-    yield
-    wire.disable_cost_model()
-
-
-@pytest.fixture(autouse=True)
 def _faasm_sanitize(request):
     """Per-test sanitizer lifecycle (see module docstring)."""
     marked = request.node.get_closest_marker("sanitize") is not None

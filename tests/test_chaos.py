@@ -219,24 +219,49 @@ def test_subscriber_raise_culled_mid_broadcast():
     assert _view(sub)[0] == 2.0
 
 
-@pytest.mark.sanitize
-def test_codec_error_falls_back_to_exact_wire():
-    """An int8 encode failure mid-push is rescued by re-pushing the same
-    delta on the exact wire — same fence token, so the rescue is still
-    exactly-once — and the landed value carries no quantisation error."""
-    n = INT8_WIRE_MIN_BYTES // 4                     # int8-eligible size
+def _codec_error_push(replica, framed, fault):
+    """One int8-eligible push of a non-uniform update from a host or a
+    device replica, framed (a warm reader wants frames) or in place; with
+    ``fault`` the int8 encode raises, else the push is exact."""
+    n = INT8_WIRE_MIN_BYTES // 4 * 2                 # int8-eligible size
     gt, (p,), _ = _fabric(n)
-    _view(p)[:] += 1.0
+    if framed:
+        LocalTier("reader", gt).pull(KEY)            # declares wire interest
+    upd = np.linspace(-1.0, 1.0, n, dtype=np.float32) ** 3
+    if replica == "device":
+        dv = p.to_device(KEY, track_delta=True)
+        p.update_device(KEY, dv + upd)               # device-side writes
+    else:
+        _view(p)[:] += upd
+    if not fault:
+        # a call of its own: the sanitizer tracks fence tokens process-wide
+        return gt, p, upd, p.push_delta(KEY, wire="exact",
+                                        fence=("ref", 1, 1))
     with faults.armed(faults.FaultPlan(seed=7).add("codec-error")) as plan:
         moved = p.push_delta(KEY, wire="int8", fence=("cc", 1, 1))
         assert plan.fired("codec-error") == 1
     assert p.codec_fallbacks == 1
-    assert moved > 0
-    np.testing.assert_array_equal(_global(gt), np.ones(n, np.float32))
+    return gt, p, upd, moved
+
+
+@pytest.mark.sanitize
+@pytest.mark.parametrize("framed", [True, False], ids=["framed", "inplace"])
+@pytest.mark.parametrize("replica", ["host", "device"])
+def test_codec_error_falls_back_to_exact_wire(replica, framed):
+    """An int8 encode failure mid-push is rescued by re-pushing the same
+    delta on the exact wire — same fence token, so the rescue is still
+    exactly-once — and the landed value is the exact push's, with no
+    quantisation error."""
+    gt, p, upd, moved = _codec_error_push(replica, framed, fault=True)
+    want_gt, _, _, want_moved = _codec_error_push(replica, framed,
+                                                  fault=False)
+    assert moved == want_moved > 0
+    np.testing.assert_array_equal(_global(gt), _global(want_gt))
+    np.testing.assert_array_equal(_global(gt), upd)
     # the fence token was consumed exactly once: replaying it is rejected
     _view(p)[:] += 1.0
     assert p.push_delta(KEY, wire="exact", fence=("cc", 1, 1)) == 0
-    np.testing.assert_array_equal(_global(gt), np.ones(n, np.float32))
+    np.testing.assert_array_equal(_global(gt), upd)
 
 
 # -- attempt-fence semantics at the tier level --------------------------------

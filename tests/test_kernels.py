@@ -8,7 +8,7 @@ from repro.kernels.flash_attention import flash_attention, attention_ref
 from repro.kernels.decode_attention import decode_attention, decode_attention_ref
 from repro.kernels.ssd_scan import ssd, ssd_step, ssd_ref
 from repro.kernels.moe_gmm import gmm, gmm_ref
-from repro.kernels.state_push import (apply_delta, push, quantize_delta,
+from repro.kernels.state_push import (apply_delta, quantize_delta,
                                       quantize_delta_ref)
 
 RNG = np.random.default_rng(42)
@@ -212,8 +212,6 @@ def test_state_push_roundtrip(shape, backend):
     exact = gv + (local - base)
     bound = float(np.abs(np.asarray(local - base)).max()) / 127 * 1.01 + 1e-8
     np.testing.assert_allclose(newg, exact, atol=bound)      # int8 error bound
-    p = push(local, base, gv, backend=backend)
-    np.testing.assert_allclose(p, exact, atol=1e-6)
 
 
 def test_quantize_zero_delta_is_exact():

@@ -13,6 +13,7 @@ from repro.kernels.state_push import (apply_delta, dequantize, quantize_delta,
                                       wire_nbytes)
 from repro.state.kv import GlobalTier
 from repro.state.local import INT8_WIRE_MIN_BYTES, LocalTier
+from repro.state.wire import get_codec
 
 BACKENDS = ("xla", "pallas_interpret")
 
@@ -60,8 +61,8 @@ def test_pad_region_quantises_to_zero(backend):
 
 @pytest.mark.parametrize("backend", BACKENDS)
 def test_tier_push_matches_kernel_apply(backend):
-    """LocalTier int8 push through GlobalTier.apply_quantized lands the same
-    value as applying the wire tuple with the fused kernel."""
+    """LocalTier int8 push through GlobalTier.apply_wire lands the same
+    value as applying an int8 frame of the delta with the fused kernel."""
     n = INT8_WIRE_MIN_BYTES // 4 * 2
     rng = _rng(7)
     init = rng.normal(size=n).astype(np.float32)
@@ -74,8 +75,10 @@ def test_tier_push_matches_kernel_apply(backend):
     lt.replica("w").buf.view(np.float32)[:] += upd
     lt.push_delta("w", wire="int8", backend=backend)
     got = np.frombuffer(gt.get("w", host="x"), np.float32)
-    q, s, numel = quantize_delta(init + upd, init, backend=backend)
-    want = np.asarray(apply_delta(init, q, s, backend=backend))
+    frame = get_codec("int8").encode_delta((init + upd) - init,
+                                           backend=backend)
+    want = np.asarray(apply_delta(init, frame.payload, frame.scales,
+                                  backend=backend))
     np.testing.assert_allclose(got, want, atol=1e-5)
 
 
@@ -216,14 +219,16 @@ def test_int8_push_of_4mb_key_moves_under_30_percent():
 
 
 def test_apply_quantized_accounts_wire_bytes():
+    """An int8 frame applied through GlobalTier.apply_wire is accounted at
+    its wire bytes, not the value's."""
     n = 1024
     gt = GlobalTier()
     gt.set("w", np.zeros(n, np.float32).tobytes(), host="up")
     gt.reset_metrics()
     delta = np.full(n, 0.5, np.float32)
-    q, s, numel = quantize_delta(delta, np.zeros(n, np.float32))
-    q, s = np.asarray(q), np.asarray(s)
-    moved = gt.apply_quantized("w", q, s, numel, host="h0")
+    frame = get_codec("int8").encode_delta(delta)
+    q, s = frame.payload, frame.scales
+    moved = gt.apply_wire("w", frame, host="h0")
     wire = wire_nbytes(q, s)
     assert moved == wire == q.nbytes + s.nbytes
     assert gt.bytes_pushed["h0"] == wire      # not the 4 KB of value bytes

@@ -118,8 +118,7 @@ def _quantize(n):
 def _encode(n):
     """The served push's encode: one executable, delta rows included."""
     return (lambda a, b: state_push._encode_pallas(
-                a, b, qmax=127.0, fp8=False, interpret=False,
-                with_residual=True),
+                a, b, interpret=False, with_residual=True),
             [((n,), F32), ((n,), F32)])
 
 
@@ -187,3 +186,22 @@ def test_equal_width_flash_attention_lowers_as_before(one_chip):
     assert "tpu_custom_call" in hlo
     got = hashlib.sha256(_without_locations(hlo).encode()).hexdigest()
     assert got == QWEN_FLASH_HLO_SHA256
+
+
+# sha256 of ``_without_locations`` of the served push's lowered encode
+# (``_encode_pallas`` over serve/stats' 151,936 f32), taken when the codec
+# still carried a qmax argument and an fp8 branch (JAX 0.9.0)
+SERVE_STATS_ENCODE_HLO_SHA256 = (
+    "2861271b195f0c9054ee9e71a512f09a07f3ca4de39379465637663df30dc904")
+
+
+def test_served_encode_lowers_as_before(one_chip):
+    """The int8 encode every served call runs under the replica lock is
+    the same program, kernel and HLO around it, as before the codec lost
+    its other tiers."""
+    fn, shapes = _encode(SERVE_STATS_NUMEL)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
+    hlo = jax.jit(fn).lower(*args).as_text()
+    assert "tpu_custom_call" in hlo
+    got = hashlib.sha256(_without_locations(hlo).encode()).hexdigest()
+    assert got == SERVE_STATS_ENCODE_HLO_SHA256

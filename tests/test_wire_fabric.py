@@ -91,6 +91,31 @@ def test_repeated_int8_pulls_carry_residual():
     assert puller.replica("w").pull_residual is not None
 
 
+@pytest.mark.parametrize("n", [1, 5, 130, 1000, 4097, 151936])
+def test_pull_wire_int8_converges_with_pull_residual(n):
+    """``GlobalTier.pull_wire`` int8 refreshes at odd sizes: the residual
+    handed back holds exactly what the replica is behind by, and stays
+    within half a step of each row's scale — debt carried, not lost."""
+    gt, pusher, puller = _setup(n)
+    view = pusher.replica("w").buf.view(np.float32)
+    base_version = puller.replica("w").global_version
+    replica = np.zeros(n, np.float32)
+    residual = None
+    rng = _rng(n)
+    for _ in range(8):
+        view[:] += (rng.normal(size=n) * 0.01).astype(np.float32)
+        pusher.push_delta("w", wire="exact")    # global moves exactly
+        frame, base_version, residual = gt.pull_wire(
+            "w", base_version, wire="int8", residual=residual,
+            exclude_origin=puller.origin_id, host="puller")
+        assert frame.wire == "int8" and frame.numel == n
+        replica += frame.decode()
+    want = _global(gt)
+    np.testing.assert_allclose(replica + residual, want, atol=1e-5)
+    half_step = np.repeat(frame.scales[:, 0], 128)[:n] / 2
+    assert np.all(np.abs(replica - want) <= half_step * (1 + 1e-5) + 1e-6)
+
+
 def test_stale_base_falls_back_to_full_pull():
     """A base older than the retained window floor can't be served as a
     delta: the pull degrades to a full (exact) re-pull."""
@@ -136,13 +161,26 @@ def test_pull_after_grow_falls_back():
     np.testing.assert_array_equal(got[n:], 5.0)
 
 
-def test_pull_rejects_bogus_wire():
+@pytest.mark.parametrize("wire", ["bogus", "int4", "fp8"])
+def test_pull_rejects_bogus_wire(wire):
     n = INT8_WIRE_MIN_BYTES // 4
     gt, pusher, puller = _setup(n)
     pusher.replica("w").buf.view(np.float32)[:] += 1.0
     pusher.push_delta("w", wire="int8")
     with pytest.raises(ValueError):
-        puller.pull("w", wire="bogus")
+        puller.pull("w", wire=wire)
+
+
+@pytest.mark.parametrize("wire", ["bogus", "int4", "fp8"])
+def test_push_rejects_unknown_wire(wire):
+    """Only the exact and int8 wires exist; a push names one of them or
+    ``auto``, and the value is untouched when it does not."""
+    n = INT8_WIRE_MIN_BYTES // 4
+    gt, pusher, _ = _setup(n)
+    pusher.replica("w").buf.view(np.float32)[:] += 1.0
+    with pytest.raises(ValueError):
+        pusher.push_delta("w", wire=wire)
+    np.testing.assert_array_equal(_global(gt), 0.0)
 
 
 # -- peer broadcast ------------------------------------------------------------
@@ -445,26 +483,39 @@ def test_write_only_keys_retain_no_frames():
 # -- adaptive wire selection ---------------------------------------------------
 
 
-def test_policy_structural_fallbacks():
+@pytest.mark.parametrize("nbytes", [0, 1, INT8_WIRE_MIN_BYTES - 1,
+                                    INT8_WIRE_MIN_BYTES])
+@pytest.mark.parametrize("dtype", [np.float32, np.float64, np.int32,
+                                   np.int64, np.bool_])
+def test_policy_structural_fallbacks(dtype, nbytes):
+    """Non-float values, and float values under INT8_WIRE_MIN_BYTES, ride
+    the exact wire whatever the policy has learned."""
     p = WirePolicy()
-    assert p.select(INT8_WIRE_MIN_BYTES - 1, np.float32) == "exact"
-    assert p.select(1 << 20, np.int64) == "exact"
-    assert p.select(1 << 20, np.float32) == "int8"
+    want = ("int8" if np.dtype(dtype).kind == "f"
+            and nbytes >= INT8_WIRE_MIN_BYTES else "exact")
+    assert p.select(nbytes, dtype) == want
+    assert p.wire == "int8"                     # the learned choice is kept
 
 
-def test_policy_flips_after_damping_and_back():
-    p = WirePolicy(damping=3)
+@pytest.mark.parametrize("damping", [1, 2, 3, 5])
+def test_policy_flips_after_damping_and_back(damping):
+    p = WirePolicy(damping=damping)
     bad = dict(delta_absmax=1.0, density=0.9, residual_ratio=2.0)
     good = dict(delta_absmax=1.0, density=0.9, residual_ratio=0.001)
-    p.observe(**bad)
-    p.observe(**bad)
-    assert p.wire == "int8"                     # not yet: damping holds
-    p.observe(**bad)
-    assert p.wire == "exact"                    # 3 consecutive -> flip
-    p.observe(**good)
-    p.observe(**good)
+    for _ in range(2):
+        for _ in range(damping - 1):
+            p.observe(**bad)
+        assert p.wire == "int8"                 # not yet: damping holds
+        p.observe(**good)                       # confirming: streak resets
+    for _ in range(damping):
+        p.observe(**bad)
+    assert p.wire == "exact"                    # damping consecutive -> flip
+    for _ in range(damping - 1):
+        p.observe(**good)
+    assert p.wire == "exact"
     p.observe(**good)
     assert p.wire == "int8"                     # healthy again -> flip back
+    assert p.flips == 2
 
 
 def test_policy_flip_flop_damped():
@@ -506,6 +557,88 @@ def test_policy_exact_observations_never_vote_int8():
     assert p.select(big, f32) == "exact"        # then back until evidence
     p.observe(delta_absmax=1.0, density=0.9, residual_ratio=0.0)
     assert p.wire == "int8"                     # healthy probe re-qualifies
+
+
+@pytest.mark.parametrize("probe_after", [1, 4, 8])
+def test_policy_reprobes_int8_every_probe_after(probe_after):
+    """Backed off to exact, the policy routes one push onto int8 after
+    every ``probe_after`` dense exact pushes; a failed probe keeps exact
+    without a flip, a clean one re-qualifies int8."""
+    p = WirePolicy(damping=1, probe_after=probe_after)
+    big, f32 = 1 << 20, np.float32
+    bad = dict(delta_absmax=1.0, density=0.9, residual_ratio=2.0)
+    p.observe(**bad)
+    assert p.wire == "exact" and p.flips == 1
+    for _ in range(3):
+        for _ in range(probe_after):
+            assert p.select(big, f32) == "exact"
+            p.observe(delta_absmax=1.0, density=0.9)
+        assert p.select(big, f32) == "int8"     # the probe push
+        p.observe(**bad)                        # it fails: stay on exact
+        assert p.wire == "exact" and p.flips == 1
+    for _ in range(probe_after):
+        p.observe(delta_absmax=1.0, density=0.9)
+    assert p.select(big, f32) == "int8"
+    p.observe(delta_absmax=1.0, density=0.9, residual_ratio=0.0)
+    assert p.wire == "int8" and p.flips == 2
+
+
+_CAP, _DENSE = 0.25, 1.0 / 256                  # WirePolicy's defaults
+
+
+@pytest.mark.parametrize("ratio,density,want", [
+    (_CAP, 1.0, "int8"),
+    (float(np.nextafter(_CAP, 1.0)), 1.0, "exact"),
+    (0.0, _DENSE, "int8"),
+    (0.0, float(np.nextafter(_DENSE, 0.0)), "exact"),
+    (None, _DENSE, "int8"),
+    (None, float(np.nextafter(_DENSE, 0.0)), "exact"),
+], ids=["at-cap", "over-cap", "at-min-density", "under-min-density",
+        "exact-push-at-min-density", "exact-push-under-min-density"])
+def test_policy_residual_cap_and_min_density_edges(ratio, density, want):
+    """``residual_cap`` and ``min_density`` are strict bounds: a residual
+    at the cap or a density at the floor keeps int8; one ULP past either
+    votes exact, from an int8 push or an exact one."""
+    p = WirePolicy(damping=1)
+    assert (p.residual_cap, p.min_density) == (_CAP, _DENSE)
+    p.observe(delta_absmax=1.0, density=density, residual_ratio=ratio)
+    assert p.wire == want
+
+
+@pytest.mark.parametrize("start", ["int8", "exact"])
+def test_policy_noop_push_teaches_nothing(start):
+    """A push whose delta is all zero neither votes, nor advances a
+    damping streak, nor advances the re-probe clock."""
+    p = WirePolicy(damping=2, probe_after=1)
+    bad = dict(delta_absmax=1.0, density=0.9, residual_ratio=2.0)
+    good = dict(delta_absmax=1.0, density=0.9, residual_ratio=0.0)
+    if start == "exact":
+        p.observe(**bad)
+        p.observe(**bad)
+    assert p.wire == start
+    flips = p.flips
+    for ratio in (2.0, 0.0, None):
+        for density in (0.0, 0.9):
+            p.observe(delta_absmax=0.0, density=density,
+                      residual_ratio=ratio)
+    assert p.wire == start and p.flips == flips
+    assert p.select(1 << 20, np.float32) == start   # no probe came due
+    p.observe(**(good if start == "exact" else bad))
+    assert p.wire == start                      # one vote: the streak was 0
+
+
+def test_policy_pull_select_does_not_spend_the_probe():
+    """``probe=False`` reads the choice without consuming a due probe,
+    however often it is asked."""
+    p = WirePolicy(damping=1, probe_after=2)
+    big, f32 = 1 << 20, np.float32
+    p.observe(delta_absmax=1.0, density=0.9, residual_ratio=2.0)
+    p.observe(delta_absmax=1.0, density=0.9)
+    p.observe(delta_absmax=1.0, density=0.9)
+    for _ in range(5):
+        assert p.select(big, f32, probe=False) == "exact"
+    assert p.select(big, f32) == "int8"
+    assert p.select(big, f32) == "exact"
 
 
 def test_auto_wire_end_to_end():
