@@ -1394,6 +1394,12 @@ class FaasmRuntime:
         g("faasm_wire_codec_fallbacks_total",
           "int8 encodes rescued by the exact wire").set(
               sum(t.codec_fallbacks for t in tiers))
+        g("faasm_wire_host_encodes_total",
+          "int8 encodes of host-resident operands by the host codec").set(
+              sum(t.host_encodes for t in tiers))
+        g("faasm_wire_device_encodes_total",
+          "int8 encodes of device operands by a device kernel").set(
+              sum(t.device_encodes for t in tiers))
         g("faasm_wire_policy_flips_total",
           "damped WirePolicy wire switches").set(
               sum(t.policy_flips() for t in tiers))

@@ -3,9 +3,11 @@
 ``kernels/state_push/ops.py`` is the right home for device-resident values,
 but for host-resident numpy replicas the JAX dispatch round-trip *is* the
 cost: at 64 KB the eager ``_to_rows`` → jit → ``np.asarray`` chain has a
-~1.7 ms floor that dwarfs the math.  This module is the fast path
-``ops.quantize_delta`` takes when both operands are plain ``np.ndarray`` on
-an ``xla`` (host) backend: one chunked pass that fuses delta, per-row absmax
+~1.7 ms floor that dwarfs the math, and on an accelerator the round trip
+also queues behind whatever the device runs.  This module is the path
+``ops.encode_quant`` takes when its operands are plain ``np.ndarray``, on
+every backend (``ops.quantize_delta`` takes it on an ``xla`` backend
+only): one chunked pass that fuses delta, per-row absmax
 scale, quantise, dequantise and error-feedback residual, writing straight
 into preallocated wire buffers.
 

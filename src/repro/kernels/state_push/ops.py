@@ -3,12 +3,13 @@
 Arrays are flattened and padded to (rows, 128); the pad region quantises to
 zero-delta so applying a padded push is a no-op on the pad.
 
-Two encode paths, chosen per call:
+Two encode paths, chosen per call by where the operands live
+(:func:`host_resident`), whatever the backend:
 
-* **host-native** (``hostcodec``): both operands are plain numpy and the
-  resolved backend is ``xla`` — the math is a handful of cache-resident
-  numpy passes, so the JAX dispatch round-trip (a ~1.7 ms floor at 64 KB)
-  is pure overhead and is skipped entirely.
+* **host-native** (``hostcodec``): both operands are plain numpy.  The
+  math is a handful of cache-resident numpy passes, so a device round trip
+  — two copies in, a kernel queued behind whatever else the device runs,
+  and a copy out — is pure overhead and is skipped entirely.
 * **device**: anything holding a device array goes through **one** fused
   jitted executable per backend: on ``xla`` flatten + pad + quantise +
   residual (``_encode_fused``), on Pallas flatten + pad + the quantise
@@ -17,6 +18,8 @@ Two encode paths, chosen per call:
   encoded in row chunks whose copy-out is pipelined with the next chunk's
   dispatch — async dispatch means chunk N quantises on device while chunk
   N−1's payload is crossing to the host.
+
+The backend only chooses the kernel for device operands.
 
 Every Pallas dispatch here runs inside ``jax.jit`` with the kernel and its
 static parameters as static arguments.  An eager ``pallas_call`` builds a
@@ -154,6 +157,15 @@ def _device_encode(eff, base, *, b, with_residual):
     return qn, sn, n, (resid[:n] if with_residual else None)
 
 
+def host_resident(eff, base) -> bool:
+    """The encode-path rule: True when ``eff`` is a plain numpy array and
+    ``base`` is ``None`` or one too, so :func:`encode_quant` runs the host
+    codec.  One home for the rule, shared by the dispatch and the callers
+    that tag or count which path an encode took."""
+    return isinstance(eff, np.ndarray) and (
+        base is None or isinstance(base, np.ndarray))
+
+
 def encode_quant(eff, base, *, backend: str | None = None,
                  with_residual: bool = True):
     """Fused int8 wire encode: quantise ``eff − base`` to codes in
@@ -161,12 +173,13 @@ def encode_quant(eff, base, *, backend: str | None = None,
     pass.  Returns host numpy
     ``(q int8 (R,128), scales f32 (R,1), numel, residual f32 (numel,) | None)``.
 
-    Host-resident numpy operands on the ``xla`` backend skip JAX entirely
-    (:mod:`.hostcodec`); device operands take one fused cached executable
-    with chunk-pipelined copy-out."""
+    Host-resident operands (:func:`host_resident`) take the host codec
+    (:mod:`.hostcodec`) on every backend and dispatch nothing to JAX; device
+    operands take one fused cached executable, and ``backend`` chooses its
+    kernel: ``_encode_pallas`` on Pallas, ``_encode_fused`` (with
+    chunk-pipelined copy-out) on ``xla``."""
     b = resolve_backend(backend)
-    if b == "xla" and (base is None or hostcodec.usable(eff, base)) \
-            and isinstance(eff, np.ndarray):
+    if host_resident(eff, base):
         q, s, n, resid = hostcodec.encode_quant(eff, base)
         return q, s, n, (resid if with_residual else None)
     if base is None:

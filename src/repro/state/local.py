@@ -150,6 +150,11 @@ class LocalTier:
         self._subscribed: Set[str] = set()
         self._mutex = make_mutex("tier", f"tier:{host_id}")
         self.codec_fallbacks = 0     # quantised encodes rescued by exact wire
+        # quantised encodes by path, counted under _encodes_mu: pushes of
+        # different keys encode concurrently, each under its own replica lock
+        self._encodes_mu = threading.Lock()
+        self.host_encodes = 0
+        self.device_encodes = 0
 
     # -- replica lifecycle ------------------------------------------------------
 
@@ -965,7 +970,9 @@ class LocalTier:
         Device-native when the replica has a fresh device copy: quantise
         runs on ``DeviceReplica.value``/``base`` and only the wire frame
         comes back to the host.  Otherwise the host replica buffer feeds
-        the host-native fused codec directly (no JAX dispatch)."""
+        the host-native fused codec directly, on every backend (no JAX
+        dispatch, so the write lock is never held across a device round
+        trip)."""
         gt = self.global_tier
         codec = get_codec("int8")
         tel = _TEL
@@ -1069,12 +1076,24 @@ class LocalTier:
                 residual_ratio=_mean_abs(residual) / (carried + 1e-12))
         return moved
 
-    @staticmethod
-    def _encode(tel, codec, key: str, eff, base, backend: Optional[str]):
+    def _encode(self, tel, codec, key: str, eff, base,
+                backend: Optional[str]):
         """The quantised push's codec encode, under a ``wire.encode`` span
-        while traced (a fresh shape compiles here); any failure becomes a
-        :class:`CodecFallback`."""
-        span = (tel.begin("wire.encode", "wire", key=key, wire=codec.name)
+        while traced (on the device path a fresh shape compiles here); any
+        failure becomes a :class:`CodecFallback`.  The span's ``path`` tag
+        and the ``host_encodes``/``device_encodes`` counters say which path
+        ``ops.encode_quant`` takes, by its own rule."""
+        from repro.kernels.state_push import ops
+
+        host = ops.host_resident(eff, base)
+        with self._encodes_mu:
+            if host:
+                self.host_encodes += 1
+            else:
+                self.device_encodes += 1
+        path = "host" if host else "device"
+        span = (tel.begin("wire.encode", "wire", key=key, wire=codec.name,
+                          path=path)
                 if tel is not None else None)
         try:
             return codec.encode(eff, base, backend=backend)

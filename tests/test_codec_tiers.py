@@ -184,6 +184,20 @@ def test_pallas_encode_matches_eager_kernel_bitwise(n):
     assert np.array_equal(rj.view(np.uint32), re.view(np.uint32))
 
 
+def _compiles():
+    """Start collecting the functions JAX compiles; returns the list and
+    the listener to unregister."""
+    import jax
+    compiles = []
+
+    def listen(event, duration, **kw):
+        if event == COMPILE_EVENT:
+            compiles.append(kw.get("fun_name"))
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    return compiles, listen
+
+
 _PI = "pallas_interpret"
 _PALLAS_OPS = {
     "encode_quant": lambda e, b: ops.encode_quant(e, b, backend=_PI),
@@ -206,13 +220,7 @@ def test_pallas_interpret_dispatch_compiles_once_per_shape(op):
         jax.block_until_ready(_PALLAS_OPS[op](jnp.asarray(eff),
                                               jnp.asarray(base)))
 
-    compiles = []
-
-    def listen(event, duration, **kw):
-        if event == COMPILE_EVENT:
-            compiles.append(kw.get("fun_name"))
-
-    jax.monitoring.register_event_duration_secs_listener(listen)
+    compiles, listen = _compiles()
     try:
         call(1531)
         compiles.clear()
@@ -224,15 +232,50 @@ def test_pallas_interpret_dispatch_compiles_once_per_shape(op):
         jax.monitoring.unregister_event_duration_listener(listen)
 
 
-def test_host_fast_path_skips_jax_dispatch():
-    """numpy operands on the xla backend return numpy wire buffers computed
-    by the host codec — bitwise equal to calling hostcodec directly."""
-    eff, base = _pair(130, seed=13)
-    q, s, n, resid = ops.encode_quant(eff, base, backend="xla")
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_host_fast_path_skips_jax_dispatch(backend):
+    """numpy operands return numpy wire buffers computed by the host codec
+    on every backend — bitwise equal to calling hostcodec directly, with no
+    JAX compile (a shape no other test encodes, so a device encode would
+    compile)."""
+    import jax
+    eff, base = _pair(2389, seed=13)
+    compiles, listen = _compiles()
+    try:
+        q, s, n, resid = ops.encode_quant(eff, base, backend=backend)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
     qh, sh, _, rh = hostcodec.encode_quant(eff, base)
-    assert type(q) is np.ndarray
+    assert type(q) is np.ndarray and type(s) is np.ndarray
+    assert type(resid) is np.ndarray and n == 2389
     assert np.array_equal(q, qh) and np.array_equal(s, sh)
     assert np.array_equal(resid, rh)
+    assert compiles == []
+
+
+@pytest.mark.parametrize("backend,kernel", [("xla", "_encode_fused"),
+                                            (_PI, "_encode_pallas")])
+def test_device_operand_keeps_device_encode(backend, kernel, monkeypatch):
+    """A device operand still reaches the backend's device kernel, and
+    ``host_resident`` says so: the backend chooses only that kernel."""
+    import jax.numpy as jnp
+    eff, base = _pair(1000, seed=14)
+    calls = []
+    real = getattr(ops, kernel)
+
+    def spy(*a, **kw):
+        calls.append(kernel)
+        return real(*a, **kw)
+
+    monkeypatch.setattr(ops, kernel, spy)
+    je = jnp.asarray(eff)
+    assert not ops.host_resident(je, base)
+    assert not ops.host_resident(eff, jnp.asarray(base))
+    assert ops.host_resident(eff, base) and ops.host_resident(eff, None)
+    q, s, n, resid = ops.encode_quant(je, jnp.asarray(base), backend=backend)
+    assert calls == [kernel] and n == 1000
+    deq = hostcodec.decode_rows(q, s, n)
+    np.testing.assert_allclose(deq + resid, eff - base, atol=1e-5)
 
 
 # -- WirePolicy ---------------------------------------------------------------

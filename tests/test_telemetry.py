@@ -76,7 +76,8 @@ def tiny_lm():
 
 def _serve(lm, n=2, prompt_len=8):
     """``n`` served ``infer`` calls (int8 ``serve/stats`` push) on a fresh
-    runtime; returns their call ids."""
+    runtime; returns their call ids and the runtime's metrics scraped
+    before shutdown."""
     from repro.launch.serve import make_infer_function
     model, treedef, leaves, vocab = lm
     rt = FaasmRuntime(n_hosts=1, capacity=2)
@@ -89,7 +90,7 @@ def _serve(lm, n=2, prompt_len=8):
         prompt = np.arange(prompt_len, dtype=np.int32).tobytes()
         cids = rt.invoke_many("infer", [prompt] * n)
         assert rt.wait_all(cids, timeout=120) == [0] * n
-        return cids
+        return cids, rt.metrics.snapshot()
     finally:
         rt.shutdown()
 
@@ -293,6 +294,65 @@ def test_fault_hits_become_instant_spans():
 
 # -- wire spans ---------------------------------------------------------------
 
+@pytest.mark.parametrize("replica,backend,path", [
+    ("host", "xla", "host"), ("host", "pallas_interpret", "host"),
+    ("device", "pallas_interpret", "device")])
+def test_int8_encode_path_tag_and_counter(replica, backend, path):
+    """An int8 push of a host replica encodes on the host whatever the
+    backend; a fresh f32 device replica's encodes on the device.  The
+    ``wire.encode`` span's ``path`` tag and the tier's counters say
+    which, and the push lands either way."""
+    t = telemetry.enable()
+    try:
+        gt, tier = _fabric(64 * 1024)
+        if replica == "device":
+            dv = tier.to_device(KEY, track_delta=True)
+            tier.update_device(KEY, dv + 1.0)
+        else:
+            tier.replica(KEY).buf.view(np.float32)[:] += 1.0
+        tier.push_delta(KEY, wire="int8", backend=backend)
+        enc = _spans_named(t.spans(), "wire.encode")
+    finally:
+        telemetry.disable()
+    assert [s.tags["path"] for s in enc] == [path]
+    assert (tier.host_encodes, tier.device_encodes) == (
+        (1, 0) if path == "host" else (0, 1))
+    np.testing.assert_allclose(_global(gt), 1.0, atol=1e-5)
+
+
+def test_encode_counters_lose_no_update_across_keys():
+    """Pushes of different keys encode concurrently, each under its own
+    replica lock, and the tier's encode counter loses none of them."""
+    n_keys, n_pushes = 16, 20
+    gt = GlobalTier()
+    tier = LocalTier("push0", gt)
+    keys = [f"k{i}" for i in range(n_keys)]
+    for k in keys:
+        gt.set(k, np.zeros(1024, np.float32).tobytes(), host="seed")
+        tier.pull(k)
+        tier.snapshot_base(k)
+
+    def pusher(k):
+        for _ in range(n_pushes):
+            tier.replica(k).buf.view(np.float32)[:] += 1.0
+            tier.push_delta(k, wire="int8")
+
+    threads = [threading.Thread(target=pusher, args=(k,)) for k in keys]
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(switch)
+    assert not any(t.is_alive() for t in threads)
+    assert (tier.host_encodes, tier.device_encodes) == (n_keys * n_pushes, 0)
+    for k in keys:
+        np.testing.assert_allclose(_global(gt, k), n_pushes, atol=1e-3)
+
+
 def test_wire_span_tags():
     t = telemetry.enable()
     n = 64 * 1024                     # big enough for the int8 wire
@@ -335,7 +395,7 @@ def test_infer_body_spans_nest_under_their_call(tiny_lm):
     with its id, inside ``call.exec`` (the encode inside ``wire.push``)."""
     t = telemetry.enable()
     try:
-        cids = _serve(tiny_lm, n=2)
+        cids, _ = _serve(tiny_lm, n=2)
         got = t.spans()
     finally:
         telemetry.disable()
@@ -360,13 +420,23 @@ def test_infer_body_spans_nest_under_their_call(tiny_lm):
         assert tags == {
             "nbytes": sum(x.nbytes for x in leaves), "leaves": len(leaves)}
         assert one["wire.encode"].tags == {"key": "serve/stats",
-                                           "wire": "int8"}
+                                           "wire": "int8", "path": "host"}
         # the waits for the replica lock in the stats pull and base snapshot
         locks = _spans_named(mine, "state.lock")
         assert {s.tags["site"] for s in locks} == {"pull", "base"}
         assert all(_within(s, ex) for s in locks)
     # one snapshot, one placement: the first bind places, the other binds
     assert sorted(placed) == [False, True]
+
+
+def test_served_pushes_count_as_host_encodes(tiny_lm):
+    """Every served call's int8 ``serve/stats`` push encodes the host
+    replica on the host: the runtime publishes one host encode per call
+    and no device encode."""
+    _, got = _serve(tiny_lm, n=3)
+    assert got["faasm_wire_host_encodes_total"] == 3
+    assert got["faasm_wire_device_encodes_total"] == 0
+    assert got["faasm_wire_codec_fallbacks_total"] == 0
 
 
 def test_compile_inside_a_call_is_a_jax_compile_span():
@@ -423,7 +493,7 @@ def test_mirrored_spans_share_the_profiler_clock(tiny_lm, tmp_path):
         mark = clock.now_ns()
         with jax.profiler.TraceAnnotation(trace_reduce.MARK):
             pass
-        cids = _serve(tiny_lm, n=2)
+        cids, _ = _serve(tiny_lm, n=2)
     finally:
         jax.profiler.stop_trace()
         ring = {(s.name, s.call): s for s in t.spans() if s.name in MIRRORED}

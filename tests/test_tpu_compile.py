@@ -188,17 +188,17 @@ def test_equal_width_flash_attention_lowers_as_before(one_chip):
     assert got == QWEN_FLASH_HLO_SHA256
 
 
-# sha256 of ``_without_locations`` of the served push's lowered encode
-# (``_encode_pallas`` over serve/stats' 151,936 f32), taken when the codec
-# still carried a qmax argument and an fp8 branch (JAX 0.9.0)
+# sha256 of ``_without_locations`` of the device-replica int8 encode
+# (``_encode_pallas`` over 151,936 f32, serve/stats' size), taken when the
+# codec still carried a qmax argument and an fp8 branch (JAX 0.9.0)
 SERVE_STATS_ENCODE_HLO_SHA256 = (
     "2861271b195f0c9054ee9e71a512f09a07f3ca4de39379465637663df30dc904")
 
 
 def test_served_encode_lowers_as_before(one_chip):
-    """The int8 encode every served call runs under the replica lock is
-    the same program, kernel and HLO around it, as before the codec lost
-    its other tiers."""
+    """The int8 encode of a device replica (host replicas, such as the
+    served ``serve/stats``, encode on the host) is the same program, kernel
+    and HLO around it, as before the codec lost its other tiers."""
     fn, shapes = _encode(SERVE_STATS_NUMEL)
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
     hlo = jax.jit(fn).lower(*args).as_text()
